@@ -167,7 +167,8 @@ def bench_gossip_mix(k=16, P=262144, graph="torus"):
     W = jnp.asarray(topo_graph.plan(graph, k, seed=0).mixing)
     out = ops.gossip_mix(rows_x, W)
     expect = ref.gossip_mix_ref(rows_x, W)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))  # bitwise
+    # different accumulation order from the matmul oracle: a few float32 ulps
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=1e-6, atol=1e-6)
     us_k = _time(lambda: ops.gossip_mix(rows_x, W))
     us_r = _time(lambda: ref.gossip_mix_ref(rows_x, W))
     Pp = pspace.padded_dim
@@ -178,7 +179,7 @@ def bench_gossip_mix(k=16, P=262144, graph="torus"):
     return [
         csv_line(
             f"gossip_mix_pallas_{graph}_k{k}_P{Pp}", us_k,
-            f"bytes={bytes_moved};spectral_gap={gap:.3f};bitwise_vs_ref=1",
+            f"bytes={bytes_moved};spectral_gap={gap:.3f};allclose_vs_ref=1",
         ),
         csv_line(f"gossip_mix_xla_ref_{graph}_k{k}_P{Pp}", us_r, "matmul_reference=1"),
     ]
@@ -193,9 +194,10 @@ def bench_compress(k=16, P=262144, bits=18, clip=1.0):
     traffic (rows read + pads read + ciphertext write) — so ``gb_per_s`` is
     *delivered* bandwidth and its ordering equals the wall-time ordering:
     the fused entry beats the staged one iff it is actually faster.  The
-    staged path additionally materializes ~4 more row-block traversals
+    staged path additionally materializes ~3 more row-block traversals
     (see ``repro.roofline.analysis.compress_traffic``).  Outputs are
-    asserted bitwise-equal before timing.
+    asserted to decode within one quantization step of each other before
+    timing (the two norm reductions are different programs).
     """
     pspace = _row_space(P, seed=k)
     rows_f = _stacked_rows(pspace, k, seed=3)
@@ -210,7 +212,8 @@ def bench_compress(k=16, P=262144, bits=18, clip=1.0):
 
     fused = ops.clip_quant_mask(rows_f, masks, clip, bits, dim=pspace.dim)
     expect = staged(rows_f, masks)
-    np.testing.assert_array_equal(np.asarray(fused), np.asarray(expect))  # bitwise
+    steps = (np.asarray(fused) - np.asarray(expect)).view(np.int32)
+    assert np.abs(steps).max() <= 1, "fused and staged ciphertexts differ by > 1 step"
     us_f = _time(lambda: ops.clip_quant_mask(rows_f, masks, clip, bits, dim=pspace.dim))
     us_s = _time(lambda: staged(rows_f, masks))
     base = jax.default_backend()
@@ -220,7 +223,7 @@ def bench_compress(k=16, P=262144, bits=18, clip=1.0):
     out = [
         csv_line(
             f"compress_fused_k{k}_P{Pp}", us_f,
-            f"bytes={bytes_moved};bits={bits};bitwise_vs_staged=1;"
+            f"bytes={bytes_moved};bits={bits};within_one_step_of_staged=1;"
             f"staged_over_fused_speedup={us_s / us_f:.2f}x",
         ),
         csv_line(f"compress_staged_k{k}_P{Pp}", us_s, "three_dispatches=1"),

@@ -19,12 +19,15 @@ import argparse
 import sys
 import time
 
+from repro.utils import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true", help="third-size datasets, half rounds")
     ap.add_argument("--skip-tables", action="store_true", help="kernels + roofline only")
     args = ap.parse_args()
+    enable_compile_cache()
     t0 = time.time()
 
     from benchmarks import fig_tradeoff, kernel_bench, roofline_table, table_compare

@@ -13,8 +13,8 @@ the composition decided by two config flags.  Here the chain is a
     NoiseStage     server-side Gaussian mechanism on the sum       [sum]
 
     FusedCompressStage = ClipStage→QuantizeStage→MaskStage collapsed into
-    the one-pass ``clip_quant_mask`` Pallas kernel: one HBM read of the
-    cohort rows, one ciphertext write, bitwise the staged composition.  It
+    the ``clip_quant_mask`` kernel: a norm pass plus one P-tiled encode
+    pass, ciphertexts within one quantization step of the staged ones.  It
     records the *same three* ``StageRecord``s (clip/quantize/mask), so the
     accountant and every records consumer cannot tell the paths apart.
     ``fuse_pipeline`` rewrites any matching composition;  ``build_pipeline``
@@ -31,7 +31,8 @@ exactly what ran — the per-region DP accounting is driven entirely by the
 
 ``build_pipeline`` maps a :class:`~repro.api.config.PrivacyConfig` onto the
 three canonical compositions (plain / secure-agg / DP), reproducing the
-legacy chains bit-for-bit; hand-compose stages for anything else, e.g.
+legacy chains (bit-for-bit unfused; fused, the DP ciphertexts within one
+quantization step); hand-compose stages for anything else, e.g.
 central DP without masking::
 
     PrivacyPipeline((ClipStage(1.0), NoiseStage(dp_cfg)), weighting="uniform")
@@ -247,12 +248,13 @@ class FusedCompressStage:
     """ClipStage → QuantizeStage → MaskStage as ONE pass over the rows.
 
     Dispatches the fused ``clip_quant_mask`` kernel (``kernels/compress.py``):
-    per-row L2 norm + clip factor + fixed-point ring encode + one-time pad
-    with one HBM read of the cohort block and one ciphertext write, where
-    the staged composition traverses it six times.  Bitwise-identical to
-    the staged stages (interpret mode; pinned by tests/test_property.py),
-    and records the *same three* ``StageRecord``s in the same order, so DP
-    accounting and wire-byte pricing are unchanged by the fusion.
+    per-row L2 norm + clip factor, then fixed-point ring encode + one-time
+    pad in one P-tiled pass — four HBM traversals of the cohort block where
+    the staged composition makes seven.  Its ciphertexts decode within one
+    quantization step of the staged stages' (pinned by
+    tests/test_property.py), and it records the *same three*
+    ``StageRecord``s in the same order, so DP accounting and wire-byte
+    pricing are unchanged by the fusion.
     """
 
     clip: float

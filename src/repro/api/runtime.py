@@ -124,14 +124,10 @@ class RuntimeContext:
         # measured FLOPs of one full local round (compute model for emissions)
         sample = task.clients[0].stacked_steps(train.batch_size, train.local_steps, 0)
         sample = {k: jnp.asarray(v) for k, v in sample.items()}
-        try:
-            lowered = jax.jit(
-                lambda p, b: self.trainer(p, b, jnp.float32(0.0), self.zero_corr)
-            ).lower(task.params0, sample)
-            cost = lowered.compile().cost_analysis()
-            self.round_flops = float(cost.get("flops", 0.0)) or self._fallback_flops()
-        except Exception:
-            self.round_flops = self._fallback_flops()
+        lowered = jax.jit(
+            lambda p, b: self.trainer(p, b, jnp.float32(0.0), self.zero_corr)
+        ).lower(task.params0, sample)
+        self.round_flops = float(lowered.compile().cost_analysis()["flops"])
         self.model_bytes = float(self.pspace.nbytes)
         self.param_dim = self.pspace.dim
         # EF top-k residual bank: one ParamSpace row per client, fed to and
@@ -161,9 +157,6 @@ class RuntimeContext:
             self.engine = engine_runtime.EngineRuntime(
                 trace, cfg.engine, train.n_clients, base_durs
             )
-
-    def _fallback_flops(self) -> float:
-        return 6.0 * self.pspace.dim * self.train.batch_size * self.train.local_steps
 
     # ------------------------------------------------------------------
     def checkpoint_round(self, strategy, rnd: int) -> None:

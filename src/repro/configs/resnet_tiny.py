@@ -2,7 +2,8 @@
 
 Not part of the assigned-architecture pool; this is the architecture the
 paper's Tables I/II are built on (MNIST / CIFAR-10 federated clients).
-Registered here so `--arch resnet-tiny` works in the FL drivers.
+``chip_smoke.py`` runs ``CONFIG`` at this full width (P = 4,696,394); the
+examples and table benchmarks use narrower ResNets of the same family.
 """
 from repro.models.resnet import ResNetConfig
 

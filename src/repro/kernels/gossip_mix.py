@@ -34,7 +34,9 @@ from jax.experimental import pallas as pl
 def _gossip_kernel(w_ref, x_ref, o_ref):
     w = w_ref[...]  # (k, k) float32 mixing matrix, same block every step
     x = x_ref[...]  # (k, block_p) float32 row tile
-    o_ref[...] = jnp.dot(w, x, preferred_element_type=jnp.float32)
+    # HIGHEST: a full float32 contraction on the MXU, not one bf16 pass
+    o_ref[...] = jnp.dot(w, x, precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
 
 
 def gossip_mix(rows, mixing, *, block_p: int = 2048, interpret: bool = True):

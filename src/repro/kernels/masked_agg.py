@@ -13,6 +13,11 @@ one fewer full read+write of the parameter vector).
 Grid over parameter blocks; the (small) client axis is reduced inside the
 kernel.  Blocks are (n_clients, block_p) uint32 tiles in VMEM; block_p
 defaults to 2048 = 8 x 256 lanes.
+
+The ring sums run in int32 (bitcast before the reduction): Mosaic does not
+reduce unsigned integers, and two's-complement addition wraps exactly like
+uint32 addition — both are mod 2^32 — so the result stays bitwise equal to
+the uint32 oracle in ``kernels/ref.py``.
 """
 from __future__ import annotations
 
@@ -24,11 +29,10 @@ from jax.experimental import pallas as pl
 
 
 def _agg_kernel(masked_ref, masks_ref, o_ref, *, scale: float):
-    masked = masked_ref[...]  # (n, block_p) uint32
-    masks = masks_ref[...]
-    total = jnp.sum(masked, axis=0, dtype=jnp.uint32) - jnp.sum(masks, axis=0, dtype=jnp.uint32)
-    signed = jax.lax.bitcast_convert_type(total, jnp.int32)
-    o_ref[...] = signed.astype(jnp.float32) * jnp.float32(1.0 / scale)
+    masked = jax.lax.bitcast_convert_type(masked_ref[...], jnp.int32)  # (n, block_p)
+    masks = jax.lax.bitcast_convert_type(masks_ref[...], jnp.int32)
+    total = jnp.sum(masked, axis=0, dtype=jnp.int32) - jnp.sum(masks, axis=0, dtype=jnp.int32)
+    o_ref[...] = total.astype(jnp.float32) * jnp.float32(1.0 / scale)
 
 
 def masked_aggregate(masked, masks, clip: float, bits: int, *, block_p: int = 2048,

@@ -90,25 +90,27 @@ def masked_aggregate(masked, masks, clip: float, bits: int, *, block_p: int = 20
         )
 
 
-@functools.partial(jax.jit, static_argnames=("clip", "bits", "dim", "block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("clip", "bits", "dim", "block_p", "interpret"))
 def clip_quant_mask(rows, masks, clip: float, bits: int, *, dim: Optional[int] = None,
-                    block_k: int = 8, interpret: Optional[bool] = None):
-    """Fused delta-to-wire compression: clip + quantize + mask in one pass
-    (see compress.py).  rows (k, P) float32, masks (k, P) uint32 -> (k, P)
-    uint32 ciphertext; ``dim`` bounds the L2 norm to the unpadded columns.
+                    block_p: int = 2048, interpret: Optional[bool] = None):
+    """Fused delta-to-wire compression: a norm pass, then clip + quantize +
+    mask in one P-tiled kernel pass (see compress.py).  rows (k, P) float32,
+    masks (k, P) uint32 -> (k, P) uint32 ciphertext; ``dim`` bounds the L2
+    norm to the unpadded columns.
 
     Dispatch mirrors ``RuntimeContext.weighted_sum``: on TPU the Pallas
     kernel runs (Mosaic lowering); on CPU the interpreter would be strictly
     slower than XLA, so ``interpret=None`` routes to the *same fused math*
-    as one XLA expression (``ref.clip_quant_mask_ref`` — bitwise identical
-    to the kernel in interpret mode, which tests/test_property.py pins).
+    as one XLA expression (``ref.clip_quant_mask_ref``).  The paths are
+    different programs, so their ciphertexts agree to within one
+    quantization step, not bitwise (tests/test_kernels.py pins this).
     Pass ``interpret=True`` to force the Pallas interpreter.
     """
     with jax.named_scope("repro.kernels/clip_quant_mask"):
         if interpret is None and default_interpret():
             return ref_mod.clip_quant_mask_ref(rows, masks, clip, bits, dim=dim)
         return cp.clip_quant_mask(
-            rows, masks, clip, bits, dim=dim, block_k=block_k,
+            rows, masks, clip, bits, dim=dim, block_p=block_p,
             interpret=_resolve(interpret),
         )
 

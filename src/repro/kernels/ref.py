@@ -1,4 +1,9 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
+
+Contractions ask for ``Precision.HIGHEST``: on a TPU the default float32
+matmul is one bf16 pass, which would make the oracle less exact than the
+kernels it checks.
+"""
 from __future__ import annotations
 
 from typing import Optional
@@ -40,7 +45,8 @@ def staleness_aggregate_ref(deltas, weights):
         Σ_i w_i · delta_i
     """
     return jnp.einsum(
-        "kp,k->p", deltas.astype(jnp.float32), weights.astype(jnp.float32)
+        "kp,k->p", deltas.astype(jnp.float32), weights.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -52,7 +58,7 @@ def gossip_mix_ref(rows, mixing):
     """
     return jnp.dot(
         mixing.astype(jnp.float32), rows.astype(jnp.float32),
-        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )
 
 
@@ -64,10 +70,11 @@ def clip_quant_mask_ref(rows, masks, clip: float, bits: int, dim=None):
 
         encode( clip_L2(row, c) ) + pad   (mod 2^32)
 
-    ``dim`` bounds the norm reduction to the valid (unpadded) columns.
-    Bitwise-identical to the staged ClipStage -> QuantizeStage -> MaskStage
-    composition AND to the Pallas kernel in interpret mode: the expressions
-    (and reduction lengths) are kept exactly the stages' own.
+    ``dim`` bounds the norm reduction to the valid (unpadded) columns.  The
+    expressions (and reduction lengths) are the staged ClipStage ->
+    QuantizeStage -> MaskStage composition's own; being one program rather
+    than three, it matches them and the Pallas kernel to within one
+    quantization step.
     """
     rows = rows.astype(jnp.float32)
     dim = rows.shape[1] if dim is None else int(dim)

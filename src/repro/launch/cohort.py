@@ -1,12 +1,12 @@
 """Sharded cohort engine: a region's cohort trains across the mesh ``data``
 axis in ONE dispatch.
 
-The FL engines (`repro.fl.simulation`, `repro.fl.async_runtime`) drive local
-training through a cohort trainer that returns ``(k, P)`` ParamSpace rows.
-On a single host that trainer vmaps the k clients; this module shard_maps
-the *same vmapped body* over the ``data`` axis of the production mesh
-(``repro.launch.mesh.make_production_mesh``) so each device trains k/d
-clients and the cohort's rows are reduced across devices in-graph:
+Every strategy drives local training through a cohort trainer that returns
+``(k, P)`` ParamSpace rows; ``TrainingConfig(sharded=True)`` makes
+``RuntimeContext`` build it here.  On one device that trainer vmaps the k
+clients; this module wraps the *same vmapped body* in ``jax.shard_map``
+over the ``data`` axis of the mesh (``cohort_mesh``) so each device trains
+k/d clients and the cohort's rows are reduced across devices in-graph:
 
   * :func:`make_sharded_cohort_trainer` — drop-in replacement for
     ``client.make_cohort_trainer``: all-gathers the per-device row shards so
@@ -21,9 +21,12 @@ Cohorts that do not divide the data axis are padded by cycling clients
 modulo k; padded outputs are sliced off (and padded weights zeroed in the
 fused step), so results are independent of the padding.
 
-On CPU/tests the fallback is a 1-device ``data`` mesh — the shard_map code
-path is identical, which is what the sharded-vs-single-device equivalence
-anchor in ``tests/test_sharding.py`` pins down (allclose, rtol=1e-5).
+The mesh spans every visible device: one on a CPU test run (the
+sharded-vs-single-device anchor in ``tests/test_sharding.py``, allclose
+rtol=1e-5), four on a 2x2 TPU v5e host (``chip_smoke.py --chips 4``).
+Replication of the gathered outputs is not type-checked
+(``check_vma=False``): the all-gather makes them replicated by
+construction.
 """
 from __future__ import annotations
 
@@ -32,8 +35,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.fl import client as client_mod
 from repro.fl.paramspace import ParamSpace
@@ -69,11 +71,14 @@ def make_sharded_cohort_trainer(
     """Cohort trainer sharded over the mesh ``data`` axis.
 
     Drop-in for ``client.make_cohort_trainer``: same signature, same
-    :class:`~repro.fl.client.CohortResult` (rows replicated across devices
-    after the in-graph all-gather), so every aggregation path — plain,
-    masked-ring, DP — runs unchanged on the output.
+    :class:`~repro.fl.client.CohortResult`, handed back on the mesh's first
+    device after the in-graph all-gather, so every aggregation path —
+    plain, masked-ring, DP — runs unchanged on the output.  The mesh the
+    cohort trained on is the returned function's ``mesh`` attribute.
     """
     mesh = mesh or cohort_mesh()
+    home = mesh.devices.flat[0]
+    replicated = NamedSharding(mesh, P())
     d = mesh.shape["data"]
     single = client_mod.make_local_trainer(loss_fn, opt)
 
@@ -88,15 +93,15 @@ def make_sharded_cohort_trainer(
             gather(res.loss_first), gather(res.loss_last),
         )
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(), P("data"), P("data"), P("data")),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit
-    def run(params_global, batches, mus, corrections) -> client_mod.CohortResult:
+    def run_on_mesh(params_global, batches, mus, corrections) -> client_mod.CohortResult:
         k = jax.tree.leaves(batches)[0].shape[0]
         idx, pad = _pad_cohort(k, d)
         if pad:
@@ -111,6 +116,15 @@ def make_sharded_cohort_trainer(
             )
         return res
 
+    def run(*inputs) -> client_mod.CohortResult:
+        # the rest of the round (privacy stages, Pallas kernels, server
+        # update) runs on one device, as after the unsharded trainer: XLA
+        # cannot partition a Mosaic kernel over the mesh the rows come from.
+        # So the inputs, committed to that device, move onto the mesh first.
+        res = run_on_mesh(*jax.device_put(inputs, replicated))
+        return jax.device_put(res, home)
+
+    run.mesh = mesh
     return run
 
 
@@ -140,11 +154,11 @@ def make_sharded_cohort_step(
         loss_last = jax.lax.all_gather(res.loss_last, "data", axis=0, tiled=True)
         return row, loss_last
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(), P("data"), P("data"), P("data"), P("data")),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

@@ -115,8 +115,8 @@ def compress_traffic(k: int, P: int, bits: int = 20,
         quantize  read f32 rows + write u32 rows          2·k·P·4
         mask      read u32 rows + read u32 pads + write   3·k·P·4
 
-    Fused kernel: read f32 rows + read u32 pads + write u32 ciphertext
-    = ``3·k·P·4`` — the norm re-read happens inside VMEM, not HBM.  Both
+    Fused path: read f32 rows for the norms, then read f32 rows + read u32
+    pads + write u32 ciphertext in the P-tiled kernel = ``4·k·P·4``.  Both
     paths are far under the compute roof (a handful of FLOPs per byte), so
     the traffic ratio *is* the predicted speedup on a memory-bound part.
 
@@ -130,7 +130,7 @@ def compress_traffic(k: int, P: int, bits: int = 20,
         raise ValueError(f"density must be in (0, 1], got {density}")
     block = k * P * 4.0
     staged = 7.0 * block
-    fused = 3.0 * block
+    fused = 4.0 * block
     kept = max(1, int(round(density * P)))
     wire = kept * bits / 8.0 + (kept * 4.0 if density < 1.0 else 0.0)
     return {

@@ -33,7 +33,7 @@ def mix_rows(pspace: ParamSpace, rows: jax.Array, mixing: jax.Array) -> jax.Arra
 
     Backend-dispatched like the server reductions: the Pallas kernel on TPU
     (rows pre-padded to whole VMEM blocks), the einsum oracle on CPU.  Both
-    paths are exercised bitwise-against each other in ``tests/test_topo.py``.
+    paths are checked against each other (allclose) in ``tests/test_topo.py``.
     """
     W = jnp.asarray(mixing, jnp.float32)
     if kernel_ops.default_interpret():

@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import os
+import pathlib
 from typing import Any, Callable, Iterable, Mapping, Sequence
 
 import jax
@@ -20,6 +22,21 @@ import numpy as np
 
 PyTree = Any
 PRNGKey = jax.Array
+
+
+def enable_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache, for entry-point scripts.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and nothing
+    else is set.  Otherwise the cache goes to the fixed ``<checkout>/.jax_cache``,
+    never a path built from a temporary name, a pid or the time, which a
+    later run would never find again.  Tests leave the cache off.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    checkout = pathlib.Path(__file__).resolve().parents[2]
+    jax.config.update("jax_compilation_cache_dir", str(checkout / ".jax_cache"))
+
 
 # ---------------------------------------------------------------------------
 # PRNG helpers

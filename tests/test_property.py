@@ -180,9 +180,13 @@ def _flat_space(dim: int) -> ParamSpace:
 )
 @settings(max_examples=15, deadline=None)
 def test_fused_compress_bitwise_equals_staged_stages(k, dim, clip, bits, seed):
-    """The fused Pallas kernel (interpret mode) IS the staged ClipStage ->
-    QuantizeStage -> MaskStage composition, bit for bit, through the real
-    pipeline executor — same ciphertext, same StageRecords."""
+    """The fused Pallas kernel (interpret mode) reproduces the staged
+    ClipStage -> QuantizeStage -> MaskStage composition through the real
+    pipeline executor: same StageRecords, and ciphertexts that decode within
+    one quantization step.  Not bitwise: the fused norm is a different
+    reduction program from ClipStage's, so the clip factor may move by an
+    ulp and a value on a rounding boundary may round the other way (e.g.
+    k=1, dim=22, clip=1.0, bits=22, seed=1)."""
     ps = _flat_space(dim)
     rng = np.random.default_rng(seed)
     rows = jnp.asarray(rng.normal(0, clip, (k, dim)).astype(np.float32))
@@ -202,15 +206,19 @@ def test_fused_compress_bitwise_equals_staged_stages(k, dim, clip, bits, seed):
             out = s.apply(out, ctx)
         return np.asarray(out), ctx.records, ctx.masks
 
+    def ring_steps(a, b):  # |a - b| in quantization steps (same pads)
+        return np.abs((np.asarray(a) - np.asarray(b)).view(np.int32)).max()
+
     c_staged, rec_staged, masks = run_rows(staged)
     c_fused, rec_fused, _ = run_rows(fused)
-    np.testing.assert_array_equal(c_fused, c_staged)
+    assert c_fused.shape == c_staged.shape
+    assert ring_steps(c_fused, c_staged) <= 1
     assert rec_fused == rec_staged
     # and the Pallas interpreter itself agrees with both
     interp = compress_mod.clip_quant_mask(
         ps.pad_rows(rows), masks, clip, bits, dim=dim, interpret=True
     )
-    np.testing.assert_array_equal(np.asarray(interp), c_staged)
+    assert ring_steps(interp, c_staged) <= 1
 
 
 @given(

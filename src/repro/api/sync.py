@@ -159,6 +159,11 @@ class SyncStrategy:
                         c_deltas.append(jax.tree.map(lambda a, b: a - b, new_ci, ctx.c_locals[ci]))
                         ctx.c_locals[ci] = new_ci
 
+                # a dispatch span: it ends once the privacy pipeline and the
+                # server update are queued, not when the device has run them.
+                # Device idle under it is host dispatch cost; the wait for the
+                # device falls in ``accounting``, whose first host read comes
+                # after these programs in the device's queue
                 with tracer.span("aggregate", round=rnd, cohort=len(sel)):
                     if train.algorithm == "fednova":
                         deltas = [ctx.pspace.unravel(res.rows[j]) for j in range(len(sel))]
@@ -174,11 +179,12 @@ class SyncStrategy:
                         wire = cohort_wire_bytes(
                             records, len(sel), ctx.model_bytes, ctx.param_dim
                         )
-                    ctx.server_state = ctx.server_apply(ctx.server_state, mean_delta)
-                    if train.algorithm == "scaffold" and c_deltas:
-                        ctx.server_state = server_mod.scaffold_update_c(
-                            ctx.server_state, c_deltas, train.n_clients
-                        )
+                    with tracer.span("server_update", round=rnd):
+                        ctx.server_state = ctx.server_apply(ctx.server_state, mean_delta)
+                        if train.algorithm == "scaffold" and c_deltas:
+                            ctx.server_state = server_mod.scaffold_update_c(
+                                ctx.server_state, c_deltas, train.n_clients
+                            )
 
                 # ---- carbon + time accounting -------------------------------
                 sel_mask, co2, dur = ctx.round_accounting(sel, t_hours)

@@ -44,13 +44,13 @@ def make_server(name: str, params: PyTree, server_lr: float = 1.0):
     state = ServerState(params, opt.init(params), c, jnp.int32(0))
 
     @jax.jit
-    def apply(state: ServerState, mean_delta: PyTree) -> ServerState:
+    def server_update(state: ServerState, mean_delta: PyTree) -> ServerState:
         # pseudo-gradient = -mean_delta
         grads = tree_scale(mean_delta, -1.0)
         params, opt_state = opt.update(state.params, grads, state.opt_state)
         return ServerState(params, opt_state, state.c, state.round + 1)
 
-    return state, apply
+    return state, server_update
 
 
 def weighted_mean_delta(deltas: list[PyTree], weights) -> PyTree:

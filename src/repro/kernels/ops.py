@@ -6,9 +6,11 @@ sequence padding to block multiples.  ``interpret`` defaults to None, which
 resolves per-backend: interpreter on CPU (this container), Mosaic lowering
 on TPU.  Pass an explicit bool to override.
 
-Every kernel dispatch runs under a ``jax.named_scope`` (``repro.kernels/*``)
-so the ops are attributable in ``jax.profiler`` traces — the device-side
-counterpart of the host-side ``repro.obs`` span tracer.
+Every kernel dispatch runs under a ``jax.named_scope`` (``repro.kernels/*``).
+On the TPU the profiler's operation events carry no scope; what the scope
+does give is the Mosaic custom call's name, which XLA takes from it
+(``%masked_agg.1 = ... custom-call``), so a kernel's device time is found
+in a ``jax.profiler`` trace by that operation name.
 """
 from __future__ import annotations
 

@@ -100,8 +100,9 @@ def make_sharded_cohort_trainer(
         check_vma=False,
     )
 
+    # named as the unsharded trainer, so one profiler reader finds either
     @jax.jit
-    def run_on_mesh(params_global, batches, mus, corrections) -> client_mod.CohortResult:
+    def cohort_train(params_global, batches, mus, corrections) -> client_mod.CohortResult:
         k = jax.tree.leaves(batches)[0].shape[0]
         idx, pad = _pad_cohort(k, d)
         if pad:
@@ -121,7 +122,7 @@ def make_sharded_cohort_trainer(
         # update) runs on one device, as after the unsharded trainer: XLA
         # cannot partition a Mosaic kernel over the mesh the rows come from.
         # So the inputs, committed to that device, move onto the mesh first.
-        res = run_on_mesh(*jax.device_put(inputs, replicated))
+        res = cohort_train(*jax.device_put(inputs, replicated))
         return jax.device_put(res, home)
 
     run.mesh = mesh

@@ -7,7 +7,14 @@ A :class:`Tracer` hands out context-manager *spans*::
 
 Each span records a monotonic start offset (``time.perf_counter`` relative
 to the tracer's epoch), a duration, its nesting depth, and an attribute
-dict.  Completed spans are kept in memory for Chrome-trace export
+dict.  For its lifetime it also holds a ``jax.profiler.TraceAnnotation``
+named ``fed.<name>`` (:data:`SPAN_PREFIX`), so a run under
+``jax.profiler.trace(dir)`` finds the program's spans on the profiler's
+clock, beside the device's operations: an idle gap on the device can be
+read against what the host was doing.  With no profiler running the
+annotation is a cheap no-op.
+
+Completed spans are kept in memory for Chrome-trace export
 (:meth:`Tracer.chrome_trace` / :meth:`Tracer.export_chrome` — the
 ``traceEvents`` "X" complete-event form that Perfetto and ``chrome://tracing``
 load directly) and, when a ``jsonl_path`` is given, streamed one JSON line
@@ -40,7 +47,12 @@ import os
 import time
 from typing import Any, Callable, Optional, TextIO
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs.streaming import StreamingHistogram
+
+#: prefix of the profiler annotation each span opens (``fed.round``, ...)
+SPAN_PREFIX = "fed."
 
 
 def _json_safe(v: Any) -> Any:
@@ -127,9 +139,10 @@ class SpanStats:
 
 
 class _Span:
-    """Live span handed out by :meth:`Tracer.span`; records itself on exit."""
+    """Live span handed out by :meth:`Tracer.span`; records itself on exit
+    and holds a ``fed.<name>`` profiler annotation while it is open."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -137,6 +150,8 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self) -> "_Span":
+        self._ann = TraceAnnotation(SPAN_PREFIX + self.name)
+        self._ann.__enter__()
         self._depth = self._tracer._enter()
         self._t0 = self._tracer._clock()
         return self
@@ -148,6 +163,7 @@ class _Span:
 
     def __exit__(self, *exc) -> None:
         t1 = self._tracer._clock()
+        self._ann.__exit__(*exc)
         self._tracer._exit(self.name, self._t0, t1 - self._t0, self._depth, self.attrs)
 
 
@@ -174,9 +190,9 @@ class NullTracer:
 
     The instrumented engines call ``ctx.tracer.span(...)`` unconditionally;
     with this tracer that is one method call returning a cached object and
-    two empty dunder calls — no clock reads, no allocation, no record —
-    which is what keeps untraced runs bitwise identical to pre-tracing
-    behavior (see ``tests/test_obs.py``).
+    two empty dunder calls — no clock reads, no allocation, no record, no
+    profiler annotation — which is what keeps untraced runs bitwise
+    identical to pre-tracing behavior (see ``tests/test_obs.py``).
     """
 
     enabled = False

@@ -8,6 +8,7 @@ import dataclasses
 import json
 import math
 import os
+import pathlib
 import tracemalloc
 
 import jax
@@ -109,6 +110,32 @@ def test_chrome_trace_schema(tmp_path):
     tr.export_chrome(out)
     trace = _validate_chrome(out)
     assert {e["name"] for e in trace["traceEvents"]} == {"a", "b"}
+
+
+def _profiled_span_names(log_dir) -> set:
+    """Names of the ``fed.*`` events on the host planes of a profiler trace."""
+    (path,) = sorted(pathlib.Path(log_dir).rglob("*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    return {e.name for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events if e.name.startswith("fed.")}
+
+
+def _profile_options():
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
+
+
+@pytest.mark.parametrize("tracer, expected", [
+    (obs.Tracer(), {"fed.outer", "fed.inner"}),
+    (obs.NULL_TRACER, set()),  # opens no annotation
+])
+def test_spans_are_profiler_annotations(tmp_path, tracer, expected):
+    with jax.profiler.trace(str(tmp_path), profiler_options=_profile_options()):
+        with tracer.span("outer"):
+            with tracer.span("inner"):
+                jax.numpy.ones(3).block_until_ready()
+    assert _profiled_span_names(tmp_path) == expected
 
 
 def test_null_tracer_is_free_and_shared():
@@ -276,11 +303,24 @@ _BASE = dict(n_clients=6, clients_per_round=3, rounds=2, local_steps=2,
              batch_size=16, eval_every=1, seed=3)
 
 _EXPECTED_SPANS = {
-    "sync": {"run", "round", "select", "train", "aggregate", "eval"},
-    "async_hier": {"run", "select", "train", "flush", "aggregate",
-                   "edge_sync", "eval"},
-    "gossip": {"run", "round", "select", "train", "mix", "eval"},
+    "sync": {"run", "round", "select", "train", "cohort_inputs", "aggregate",
+             "server_update", "accounting", "policy_update", "eval"},
+    "async_hier": {"run", "select", "train", "cohort_inputs", "flush",
+                   "aggregate", "edge_sync", "eval"},
+    "gossip": {"run", "round", "select", "train", "cohort_inputs", "mix",
+               "accounting", "policy_update", "eval"},
 }
+
+# (strategy, span, the span it sits directly inside), once per round
+_NESTED_ONCE_PER_ROUND = [
+    ("sync", "cohort_inputs", "train"),
+    ("sync", "server_update", "aggregate"),
+    ("sync", "accounting", "round"),
+    ("sync", "policy_update", "round"),
+    ("gossip", "cohort_inputs", "train"),
+    ("gossip", "accounting", "round"),
+    ("gossip", "policy_update", "round"),
+]
 
 _EXPECTED_EVENT = {"sync": "RoundEvent", "async_hier": "FlushEvent",
                    "gossip": "MixEvent"}
@@ -338,6 +378,15 @@ def observed_runs(tmp_path_factory):
     return runs
 
 
+def test_federation_spans_in_profiler_trace(tmp_path):
+    """A traced run under the profiler puts the round loop's spans on the
+    profiler's clock, beside the device's operations."""
+    fed = api.Federation(_cfg("sync"), _task(), tracer=obs.Tracer())
+    with jax.profiler.trace(str(tmp_path), profiler_options=_profile_options()):
+        fed.run()
+    assert {"fed.round", "fed.cohort_inputs", "fed.accounting"} <= _profiled_span_names(tmp_path)
+
+
 @pytest.mark.parametrize("mode", ["sync", "async_hier", "gossip"])
 def test_event_log_round_trips(observed_runs, mode):
     run = observed_runs[mode]
@@ -373,6 +422,18 @@ def test_trace_artifacts_and_manifest(observed_runs, mode):
     snap = json.load(open(os.path.join(run["dir"], "metrics.json")))
     assert snap["events"] == _BASE["rounds"]
     assert snap["co2_g_total"] > 0
+
+
+@pytest.mark.parametrize("mode, name, parent", _NESTED_ONCE_PER_ROUND)
+def test_phase_spans_nest_once_per_round(observed_runs, mode, name, parent):
+    rows = obs.read_spans(os.path.join(observed_runs[mode]["dir"], "trace.jsonl"))
+    spans = [r for r in rows if r["name"] == name]
+    parents = [r for r in rows if r["name"] == parent]
+    assert len(spans) == _BASE["rounds"]
+    for r in spans:
+        assert any(p["depth"] == r["depth"] - 1 and p["ts_us"] <= r["ts_us"]
+                   and r["ts_us"] + r["dur_us"] <= p["ts_us"] + p["dur_us"]
+                   for p in parents), (name, parent)
 
 
 @pytest.mark.parametrize("mode", ["sync", "async_hier", "gossip"])

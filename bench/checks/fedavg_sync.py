@@ -48,18 +48,18 @@ def _local_round_fn(model_module, model_key, lr: float, beta: float, dtype):
     model = {k: (list(v) if isinstance(v, tuple) else v) for k, v in model_key}
 
     @jax.jit
-    def local_round(params, images, labels):
+    def local_round(params, batches):
         p0 = jax.tree.map(lambda x: x.astype(dtype), params)
         mu0 = jax.tree.map(jnp.zeros_like, p0)
 
         def step(carry, batch):
             p, mu = carry
-            value, grads = jax.value_and_grad(model_module.loss)(p, model, batch[0], batch[1], dtype)
+            value, grads = jax.value_and_grad(model_module.loss)(p, model, batch, dtype)
             mu = jax.tree.map(lambda m, g: (beta * m + g).astype(dtype), mu, grads)
             p = jax.tree.map(lambda w, m: (w - lr * m).astype(dtype), p, mu)
             return (p, mu), value
 
-        (p, _), losses = jax.lax.scan(step, (p0, mu0), (images, labels))
+        (p, _), losses = jax.lax.scan(step, (p0, mu0), batches)
         delta = jax.tree.map(lambda a, b: (a - b).astype(jnp.float32), p, p0)
         return delta, losses.astype(jnp.float32)
 
@@ -76,7 +76,7 @@ def reference_rounds(model_module, cell, data, parts, client_seed: int, params0,
                       for k, v in sorted(cell["config"]["model"].items()))
     local_round = _local_round_fn(model_module, model_key, float(proto["client_lr"]),
                                   float(proto["client_momentum"]), dtype)
-    images, labels = data["train"]["image"], data["train"]["label"]
+    train = data["train"]
     batch, steps = proto["batch_size"], cell["traffic"]["local_steps"]
     params = jax.tree.map(lambda x: x.astype(dtype), params0)
     out = []
@@ -86,7 +86,7 @@ def reference_rounds(model_module, cell, data, parts, client_seed: int, params0,
             mean, first, last = None, [], []
             for c, w in zip(sel, sizes / sizes.sum()):
                 idx = generate.local_batches(parts[c], client_seed + int(c), batch, steps, rnd)
-                delta, losses = local_round(params, jnp.asarray(images[idx]), jnp.asarray(labels[idx]))
+                delta, losses = local_round(params, {k: jnp.asarray(v[idx]) for k, v in train.items()})
                 losses = np.asarray(losses)
                 first.append(float(losses[0]))
                 last.append(float(losses[-1]))

@@ -115,11 +115,11 @@ def forward(params: dict, model: dict, images, dtype=jnp.float32):
     return jnp.dot(pooled, params["head_w"].astype(dtype), precision=prec) + params["head_b"].astype(dtype)
 
 
-def loss(params: dict, model: dict, images, labels, dtype=jnp.float32):
-    """Mean softmax cross-entropy of one batch."""
-    logits = forward(params, model, images, dtype)
+def loss(params: dict, model: dict, batch: dict, dtype=jnp.float32):
+    """Mean softmax cross-entropy of one batch of ``"image"`` and ``"label"``."""
+    logits = forward(params, model, batch["image"], dtype)
     logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
+    return -jnp.mean(jnp.take_along_axis(logp, batch["label"][:, None], axis=1))
 
 
 def forward_flops(model: dict, image_shape) -> int:
@@ -139,7 +139,8 @@ def forward_flops(model: dict, image_shape) -> int:
     return flops + 2 * model["widths"][-1] * model["num_classes"]
 
 
-def train_flops_per_sample(model: dict, image_shape) -> int:
-    """Forward and backward: three times the forward (the backward pass
-    takes one product for the input gradient and one for the weights')."""
-    return 3 * forward_flops(model, image_shape)
+def train_flops_per_sample(model: dict, dataset: dict) -> int:
+    """Forward and backward of one image of the configuration's ``dataset``:
+    three times the forward (the backward pass takes one product for the
+    input gradient and one for the weights')."""
+    return 3 * forward_flops(model, dataset["shape"])

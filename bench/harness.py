@@ -1,16 +1,24 @@
 """One run of one benchmark cell: set-up, the timed window, the check.
 
 A cell is a configuration (``bench/configs/<name>.json``, with its plain
-reference ``bench/configs/<reference>.py``), a traffic mix
+reference ``bench/configs/<reference>.py`` and its data generator
+``bench/datasets/<generator>.py``), a traffic mix
 (``bench/traffic/<name>.json``) and the limits its check was calibrated to
 (``bench/cells/<cell>.json``).  Per-layer metrics are readers in
 ``bench/metrics/<metric>.py``.  All of them are found by the names in
-``BENCHMARK.json``, so a new cell, configuration or metric is new files.
+``BENCHMARK.json`` and the files it names, so a new cell, configuration,
+kind of data or metric is new files.
+
+A configuration's ``dataset`` names its ``generator``, whose
+``make(spec, seed)`` returns ``{"train": {key: array}, "test": {...}}``
+keyed by the program's batch keys, and ``partition_by``, the integer key
+(a label, a domain) that the clients' Dirichlet skew is drawn over.
 
 A run:
 
 1. makes the data, the Dirichlet partition and the initial weights from
-   the seed (``generate``, the reference module's ``init_params``);
+   the seed (the generator, ``generate``, the reference module's
+   ``init_params``);
 2. builds ``repro.api.Federation`` with the cell's experiment;
 3. runs the initial evaluation and ``warmup_rounds`` rounds: every program
    the window uses is compiled there (the trainer, the privacy pipeline
@@ -48,6 +56,7 @@ from bench.peaks import peaks
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_EVENTS = ("/jax/compilation_cache/cache_hits", "/jax/compilation_cache/cache_misses")
 HUGE_ROUNDS = 10 ** 9  # the window, not the round count, ends a run
+DATASET_KEYS = ("generator", "partition_by")
 
 
 def cache_size(path: Path) -> str:
@@ -117,6 +126,11 @@ def load_cell(root: Path, workload: str, overrides: Optional[dict] = None) -> di
     overrides = overrides or {}
     config = _merge(config, overrides.get("config", {}))
     traffic = _merge(traffic, overrides.get("traffic", {}))
+    missing = [k for k in DATASET_KEYS if k not in config["dataset"]]
+    if missing:
+        raise ValueError(f"{entry['file']}: \"dataset\" lacks {' and '.join(missing)}, which have "
+                         f"no default: it names its generator (bench/datasets/<generator>.py) "
+                         f"and the integer key that the partition is drawn over")
 
     def for_cell(metrics):
         return [m for m in metrics if workload in m.get("workloads", [workload])]
@@ -126,6 +140,7 @@ def load_cell(root: Path, workload: str, overrides: Optional[dict] = None) -> di
         "limits": limits, "end_to_end": for_cell(spec["end_to_end"]),
         "per_layer": for_cell(spec["per_layer"]),
         "reference": load_module(root / "bench" / "configs" / f"{config['reference']}.py"),
+        "generator": load_module(root / "bench" / "datasets" / f"{config['dataset']['generator']}.py"),
         "check": load_module(root / "bench" / "checks" / f"{traffic['check']}.py"),
         "metrics_dir": root / "bench" / "metrics",
     }
@@ -174,7 +189,7 @@ class TracedRun:
         self.param_dim = cell["reference"].param_count(cell["config"]["model"])
         self.samples = len(rounds) * self.cohort * cell["traffic"]["local_steps"] * proto["batch_size"]
         self.train_flops_per_sample = cell["reference"].train_flops_per_sample(
-            cell["config"]["model"], cell["config"]["dataset"]["shape"])
+            cell["config"]["model"], cell["config"]["dataset"])
 
 
 class _RoundSink:
@@ -214,9 +229,10 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool, 
 
     from repro.api import Federation
 
-    data = generate.make_dataset(cfg["dataset"], seeds["data"])
-    parts = generate.dirichlet_partition(data["train"]["label"], proto["n_clients"],
-                                         proto["dirichlet_alpha"], seeds["partition"])
+    data = cell["generator"].make(cfg["dataset"], seeds["data"])
+    parts = generate.dirichlet_partition(data["train"][cfg["dataset"]["partition_by"]],
+                                         proto["n_clients"], proto["dirichlet_alpha"],
+                                         seeds["partition"])
     params0 = cell["reference"].init_params(cfg["model"], seeds["weights"])
     task = program_task(cell, params0, data, parts, seeds["clients"])
     tracer = spans.SpanTracer() if trace else None

@@ -23,7 +23,7 @@ def _config(name):
 def test_resnet_tiny_counts(name, forward, params):
     cfg = _config(name)
     assert resnet_tiny.forward_flops(cfg["model"], cfg["dataset"]["shape"]) == forward
-    assert resnet_tiny.train_flops_per_sample(cfg["model"], cfg["dataset"]["shape"]) == 3 * forward
+    assert resnet_tiny.train_flops_per_sample(cfg["model"], cfg["dataset"]) == 3 * forward
     assert resnet_tiny.param_count(cfg["model"]) == params == cfg["param_count"]
     shapes = jax.eval_shape(lambda: resnet_tiny.init_params(cfg["model"], 0))
     assert sum(x.size for x in jax.tree.leaves(shapes)) == params
@@ -51,6 +51,7 @@ def test_peaks_by_device_kind():
 @pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
 def test_every_name_has_its_files(cell):
     loaded = harness.load_cell(ROOT, cell)
+    assert callable(loaded["generator"].make)
     assert loaded["traffic"]["local_steps"] > 0
     assert loaded["limits"] and set(loaded["limits"]) <= set(loaded["check"].NUMBERS)
     assert loaded["end_to_end"] and loaded["per_layer"]

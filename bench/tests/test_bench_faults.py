@@ -16,9 +16,11 @@ CELL = "cifar10-secagg-l8"
 
 
 @pytest.mark.parametrize("name", sorted(FAULTS))
-def test_fault_is_caught(name):
-    result, run = harness.run_cell(tiny.ROOT, CELL, 2 ** 32 + 3, 0.1, False, require_tpu=False,
-                                   overrides=tiny.overrides(resnet_tiny),
+@pytest.mark.parametrize("cell, seed", [("cifar10-secagg-l8", 2 ** 32 + 3),
+                                        ("mnist-secagg-l2", 2 ** 32 + 7)])
+def test_fault_is_caught(cell, seed, name):
+    result, run = harness.run_cell(tiny.ROOT, cell, seed, 0.1, False, require_tpu=False,
+                                   overrides=tiny.overrides(resnet_tiny, tiny.CELLS[cell]),
                                    fault=FAULTS[name])
     assert result["correct"] is False, run["readings"]
 

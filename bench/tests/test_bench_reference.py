@@ -7,19 +7,22 @@ from bench import harness
 from bench.configs import resnet_tiny
 from bench.tests import tiny
 
-CELL = "cifar10-secagg-l8"
 
-
-@pytest.fixture(scope="module")
-def sound():
-    result, run = harness.run_cell(tiny.ROOT, CELL, 2 ** 33 + 17, 0.2, False, require_tpu=False,
-                                   overrides=tiny.overrides(resnet_tiny))
+@pytest.fixture(scope="module", params=[("cifar10-secagg-l8", 2 ** 33 + 17),
+                                        ("mnist-secagg-l2", 2 ** 33 + 19)], ids=lambda p: p[0])
+def sound(request):
+    cell, seed = request.param
+    result, run = harness.run_cell(tiny.ROOT, cell, seed, 0.2, False, require_tpu=False,
+                                   overrides=tiny.overrides(resnet_tiny, tiny.CELLS[cell]))
     return result, run
 
 
 def test_reference_follows_the_federation(sound):
     result, run = sound
-    assert result["correct"] is True
+    assert result["correct"] is True, result["checks"]
+    channels = run["cell"]["config"]["model"]["in_channels"]
+    assert set(run["data"]["train"]) == {"image", "label"}
+    assert run["data"]["train"]["image"].shape[1:] == (16, 16, channels)
     # on the CPU both sides are float32; what is left is the secure
     # aggregation's fixed-point rounding, far under every limit
     for name, c in result["checks"].items():

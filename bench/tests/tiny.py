@@ -8,6 +8,8 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
 MODEL = {"widths": [8, 16], "depths": [1, 1], "groups": 4}
+# the image cells and the input channels of their configuration
+CELLS = {"cifar10-secagg-l8": 3, "mnist-secagg-l2": 1}
 
 
 def overrides(reference, in_channels: int = 3) -> dict:

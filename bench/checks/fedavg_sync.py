@@ -28,6 +28,12 @@ the first rounds' unstable training (losses of 13 to 18) amplifies that
 into gaps as large as the bfloat16 control's (PERF.md, "How correct is
 decided").  Leaves whose reference update is under a thousandth of the
 median leaf's are left out (none are, in ResNet-Tiny).
+
+Weights come and go as host trees.  On the device the reference holds the
+round's weights, one client's local state and the running mean, and
+nothing more: the client's update is scaled and added into the mean by
+steps that donate their first argument, each round's weights go to the
+host as soon as they are made, and the norms move one leaf at a time.
 """
 from __future__ import annotations
 
@@ -66,11 +72,25 @@ def _local_round_fn(model_module, model_key, lr: float, beta: float, dtype):
     return local_round
 
 
+# the product and the sum are kept in programs of their own, so no
+# compiler contracts them into one rounding (a fused multiply-add)
+@functools.partial(jax.jit, donate_argnums=0)
+def _scaled(delta, w):
+    return jax.tree.map(lambda d: w * d, delta)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _add_into(a, b):
+    """``a + b`` in float32, in ``a``'s type and buffer."""
+    return jax.tree.map(lambda x, y: (x.astype(jnp.float32) + y).astype(x.dtype), a, b)
+
+
 def reference_rounds(model_module, cell, data, parts, client_seed: int, params0,
                      selections, dtype=jnp.float32) -> list[dict]:
     """The reference's trajectory over the rounds in ``selections`` (the
     cohort of each): per round the clients' first-step losses, the round's
-    loss (the clients' mean last-step loss) and the server parameters."""
+    loss (the clients' mean last-step loss) and the server parameters
+    (float32, on the host).  ``params0`` is a host tree."""
     proto = cell["config"]["protocol"]
     model_key = tuple((k, tuple(v) if isinstance(v, list) else v)
                       for k, v in sorted(cell["config"]["model"].items()))
@@ -78,7 +98,8 @@ def reference_rounds(model_module, cell, data, parts, client_seed: int, params0,
                                   float(proto["client_momentum"]), dtype)
     train = data["train"]
     batch, steps = proto["batch_size"], cell["traffic"]["local_steps"]
-    params = jax.tree.map(lambda x: x.astype(dtype), params0)
+    # a copy of its own, as the round's update donates it
+    params = jax.tree.map(lambda x: jnp.array(x).astype(dtype), params0)
     out = []
     with jax.default_matmul_precision("highest"):
         for rnd, sel in enumerate(selections):
@@ -87,21 +108,25 @@ def reference_rounds(model_module, cell, data, parts, client_seed: int, params0,
             for c, w in zip(sel, sizes / sizes.sum()):
                 idx = generate.local_batches(parts[c], client_seed + int(c), batch, steps, rnd)
                 delta, losses = local_round(params, {k: jnp.asarray(v[idx]) for k, v in train.items()})
-                losses = np.asarray(losses)
+                losses = jax.device_get(losses)
                 first.append(float(losses[0]))
                 last.append(float(losses[-1]))
-                scaled = jax.tree.map(lambda d: jnp.float32(w) * d, delta)
-                mean = scaled if mean is None else jax.tree.map(jnp.add, mean, scaled)
-            params = jax.tree.map(lambda p, m: (p.astype(jnp.float32) + m).astype(dtype), params, mean)
+                scaled = _scaled(delta, jnp.float32(w))
+                mean = scaled if mean is None else _add_into(mean, scaled)
+                del delta, scaled
+            params = _add_into(params, mean)
             out.append({"first_losses": np.array(first), "loss": float(np.mean(last)),
-                        "params": jax.tree.map(lambda x: x.astype(jnp.float32), params)})
+                        "params": jax.tree.map(lambda x: np.asarray(x, np.float32),
+                                               jax.device_get(params))})
     return out
 
 
 def _leaf_norms(tree, base) -> np.ndarray:
-    diffs = jax.tree.map(lambda a, b: jnp.sqrt(jnp.sum(jnp.square(
-        a.astype(jnp.float32) - b.astype(jnp.float32)))), tree, base)
-    return np.asarray(jax.device_get(jax.tree.leaves(diffs)), np.float64)
+    """Float32 norm of each leaf's change, of host or device trees: each
+    leaf goes to the device on its own and comes back as one number."""
+    norms = jax.tree.map(lambda a, b: float(jnp.sqrt(jnp.sum(jnp.square(
+        jnp.asarray(a).astype(jnp.float32) - jnp.asarray(b).astype(jnp.float32))))), tree, base)
+    return np.array(jax.tree.leaves(norms), np.float64)
 
 
 def worst_leaf_gap(prog_tree, ref_tree, params0) -> float:

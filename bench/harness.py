@@ -29,6 +29,12 @@ A run:
 5. frees the program, then runs the plain reference over the first
    warm-up round from the same weights and cohort and compares.
 
+The check's copies of the weights (the initial ones and each warm-up
+round's) wait on the host, so the device holds only the program's arrays
+while the window runs and only the reference's while the check runs.  The
+device's bytes in use when the window opens and when the check starts, and
+its peak after the check, go to stderr.
+
 The window is stopped from a telemetry sink, which the program calls with
 each round's event at the end of the round (as it calls ``progress``).
 """
@@ -57,6 +63,11 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_EVENTS = ("/jax/compilation_cache/cache_hits", "/jax/compilation_cache/cache_misses")
 HUGE_ROUNDS = 10 ** 9  # the window, not the round count, ends a run
 DATASET_KEYS = ("generator", "partition_by")
+
+
+def bytes_on_device(devices, key: str = "bytes_in_use") -> int:
+    """A memory statistic of the fullest device; 0 where none is reported."""
+    return max(int((d.memory_stats() or {}).get(key, 0)) for d in devices)
 
 
 def cache_size(path: Path) -> str:
@@ -223,6 +234,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool, 
         raise NoChip(f"JAX found no TPU (platform {devices[0].platform!r})")
     if len(devices) < cell["chips"]:
         raise NoChip(f"the cell asks for {cell['chips']} chips, JAX sees {len(devices)}")
+    used = devices[: cell["chips"]]
     clock = CompileClock()
     cfg, traffic, proto = cell["config"], cell["traffic"], cell["config"]["protocol"]
     seeds = generate.derive_seeds(seed)
@@ -235,6 +247,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool, 
                                          seeds["partition"])
     params0 = cell["reference"].init_params(cfg["model"], seeds["weights"])
     task = program_task(cell, params0, data, parts, seeds["clients"])
+    # the program keeps the device arrays; the check's copy waits on the host
+    params0 = jax.device_get(params0)
     tracer = spans.SpanTracer() if trace else None
     sink = _RoundSink()
     fed = Federation(experiment(cell, seeds["federation"]), task, tracer=tracer, telemetry=[sink])
@@ -244,7 +258,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool, 
     warmup = traffic["warmup_rounds"]
     trace_rounds = 2 * proto["eval_every"]
     trace_dir = Path(tempfile.mkdtemp(prefix="bench-trace-")) if trace else None
-    st = {"done": 0, "checked": [], "stamps": [], "window_ann": None}
+    st = {"done": 0, "checked": [], "stamps": [], "window_ann": None, "open_bytes": 0}
     if fault is not None:
         fault(fed)
     # the clients' first-step losses of round 1, read where the trainer
@@ -262,11 +276,12 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool, 
     def on_round(event) -> None:
         st["done"] += 1
         if st["done"] <= warmup:
+            # the host copy waits for the round's server update
             st["checked"].append({"loss": event.loss, "selected": list(event.selected),
-                                  "params": fed.ctx.server_state.params})
+                                  "params": jax.device_get(fed.ctx.server_state.params)})
             if st["done"] < warmup:
                 return
-            jax.block_until_ready(fed.ctx.server_state.params)
+            st["open_bytes"] = bytes_on_device(used)
             if not measure:
                 raise WindowClosed
             if trace:
@@ -297,8 +312,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool, 
 
     n_compiles = clock.count - st.get("compile_mark", clock.mark())[0]
     compile_in_window = clock.seconds - st.get("compile_mark", clock.mark())[1]
-    used = devices[: cell["chips"]]
-    peak_bytes = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in used)
+    peak_bytes = bytes_on_device(used, "peak_bytes_in_use")
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(devices), "memory_peak_bytes": peak_bytes}
 
@@ -340,12 +354,17 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool, 
     params_checked = st["checked"]
     del fed, task, tracer, train_cohort
     gc.collect()
+    live = [a.nbytes for a in jax.live_arrays()]
+    held = (f"device bytes in use: window open {st['open_bytes']}, check start "
+            f"{bytes_on_device(used)} ({len(live)} live arrays of {sum(live)} bytes, the largest "
+            f"{max(live, default=0)}; the weights take {sum(x.nbytes for x in jax.tree.leaves(params0))})")
 
     check = cell["check"]
     params_checked[0]["first_losses"] = st["first_losses"]
     reference = check.reference_rounds(cell["reference"], cell, data, parts, seeds["clients"],
                                        params0, [params_checked[0]["selected"]])
     readings = check.readings(params_checked, reference, params0)
+    print(f"{held}; peak after the check {bytes_on_device(used, 'peak_bytes_in_use')}", file=sys.stderr)
     # the numbers compared are those the cell's file gives a limit
     limits = cell["limits"]
     correct = all(math.isfinite(readings[n]) and readings[n] <= limit for n, limit in limits.items())

@@ -58,6 +58,7 @@ from repro.core import carbon as carbon_mod
 from repro.core import orchestrator as orch
 from repro.engine.clock import SimClock
 from repro.engine.events import EventQueue
+from repro.fl import client as client_mod
 from repro.fl import hierarchy
 from repro.privacy import dp as dp_mod
 from repro.privacy.accountant import SubsampledAccountant
@@ -258,8 +259,8 @@ class AsyncHierStrategy:
             sel_local = np.flatnonzero(np.asarray(mask))[:k]
             sel_global = reg.global_ids(sel_local)
 
-        with ctx.tracer.span("train", region=reg.idx, wave=reg.waves,
-                             cohort=len(sel_global)):
+        with ctx.tracer.span("train", region=reg.idx, wave=reg.waves, cohort=len(sel_global),
+                             clients_per_pass=client_mod.CLIENTS_PER_PASS):
             res = ctx.train_cohort(reg.edge_params, sel_global, reg.waves)
 
         if ctx.engine is not None:

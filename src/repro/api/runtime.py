@@ -270,8 +270,9 @@ class RuntimeContext:
             return batches, mus, corrections
 
     def train_cohort(self, params, sel, step: int, corrections=None):
-        """One vmapped local-training dispatch of the selected cohort
-        against the shared ``params`` (the sync/async server model)."""
+        """One local-training dispatch of the selected cohort, its clients
+        trained in turn against the shared ``params`` (the sync/async server
+        model)."""
         batches, mus, corrections = self._cohort_inputs(sel, step, corrections)
         return self.cohort_trainer(params, batches, mus, corrections)
 

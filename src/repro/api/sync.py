@@ -1,7 +1,7 @@
 """Synchronous round-loop strategy (the paper's §IV protocol).
 
 One ``run`` = ``rounds`` lock-step federated rounds: carbon-aware selection,
-one vmapped cohort-training dispatch, the privacy pipeline, one server
+one cohort-training dispatch, the privacy pipeline, one server
 update, then emissions accounting and the MARL reward — emitting one typed
 :class:`~repro.api.telemetry.RoundEvent` per round.
 
@@ -131,7 +131,7 @@ class SyncStrategy:
                     )
                     sel = np.flatnonzero(np.asarray(mask))[: train.clients_per_round]
 
-                # --- cohort local training: one vmapped jit call per round ------
+                # --- cohort local training: one jit call per round -------------
                 weights = [len(ctx.clients[ci]) for ci in sel]
                 if train.algorithm == "scaffold":
                     corrs = jax.tree.map(
@@ -140,7 +140,8 @@ class SyncStrategy:
                     )
                 else:
                     corrs = None  # train_cohort broadcasts the zero correction
-                with tracer.span("train", round=rnd, cohort=len(sel)):
+                with tracer.span("train", round=rnd, cohort=len(sel),
+                                 clients_per_pass=client_mod.CLIENTS_PER_PASS):
                     res = ctx.train_cohort(
                         ctx.server_state.params, sel, rnd, corrections=corrs
                     )

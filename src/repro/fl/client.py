@@ -1,12 +1,14 @@
 """Client-side local training (paper §III-C).
 
-One jitted function runs a client's whole local round — ``lax.scan`` over the
-stacked local batches — and returns the *model delta* (w_local - w_global).
-The cohort trainer flattens the k-stacked deltas into ``(k, P)`` float32
-rows via the experiment's :class:`repro.fl.paramspace.ParamSpace` before
-they leave the jitted call, which is what every aggregation path (plain,
-masked-ring, Paillier, the fused Pallas kernels) consumes — deltas never
-materialize host-side as pytrees.
+One jitted function runs a client's whole local round — its local steps,
+unrolled over the stacked local batches — and returns the *model delta*
+(w_local - w_global).  The cohort trainers run that round for each client
+of the cohort in turn inside one jitted program (:func:`map_cohort`) and
+flatten each delta into a float32 row of the experiment's
+:class:`repro.fl.paramspace.ParamSpace`, so the cohort leaves the call as
+``(k, P)`` rows, which is what every aggregation path (plain, masked-ring,
+Paillier, the fused Pallas kernels) consumes — deltas never materialize
+host-side as pytrees.
 
 Supports the paper's client rules:
   * FedAvg        — plain local SGD/momentum
@@ -69,8 +71,8 @@ def make_local_trainer(loss_fn: Callable, opt: Optimizer) -> Callable:
             params, opt_state = opt.update(params, grads, opt_state)
             return (params, opt_state), loss
 
-        # NOTE: unrolled rather than lax.scan — XLA:CPU executes conv bodies
-        # ~13x slower inside while-loops (measured; see EXPERIMENTS.md §Notes).
+        # NOTE: unrolled rather than lax.scan — XLA:CPU ran the convolutions
+        # of a scanned local round about 13x slower than unrolled ones.
         # n_steps is static (fixed local step count), so the unroll is exact.
         carry = (params_global, opt_state)
         losses = []
@@ -84,14 +86,37 @@ def make_local_trainer(loss_fn: Callable, opt: Optimizer) -> Callable:
     return run
 
 
-def make_cohort_trainer(loss_fn: Callable, opt: Optimizer, pspace: ParamSpace) -> Callable:
-    """Vectorized local training: the whole selected cohort in ONE jitted call.
+# clients that share one pass of the model on the device in the cohort
+# trainers: :func:`map_cohort` trains them one at a time
+CLIENTS_PER_PASS = 1
 
-    This is both the CPU-simulation fast path (one dispatch per round, XLA
-    batches the per-client work) and the semantic template for the sharded
-    cohort engine (the same vmapped body shard_mapped over the mesh data
-    axis — see repro/launch/cohort.py) and the pod-scale ``fl_train_step``
-    (repro/launch/train.py).
+
+def map_cohort(train_one: Callable, pspace: ParamSpace, *cohort) -> CohortResult:
+    """Train a cohort one client after another inside the caller's jitted
+    program: ``jax.lax.map`` over the leading (k,) axis of ``cohort``.
+
+    ``train_one(*one_client) -> LocalResult`` sees one client's slice of
+    each input, so its weights are unbatched and every convolution of its
+    local round is a dense one over that client's batch (``jax.vmap`` over
+    k weight copies would make each a grouped convolution,
+    ``feature_group_count = k``), and only one client's activations are
+    live at a time.  Each delta leaves the loop as its (P,) row.
+    """
+
+    def one(xs) -> tuple:
+        res = train_one(*xs)
+        return pspace.ravel(res.delta), res.n_steps, res.loss_first, res.loss_last
+
+    return CohortResult(*jax.lax.map(one, cohort))
+
+
+def make_cohort_trainer(loss_fn: Callable, opt: Optimizer, pspace: ParamSpace) -> Callable:
+    """The whole selected cohort's local rounds in ONE jitted call.
+
+    One dispatch per round; inside it :func:`map_cohort` runs the clients
+    in turn from the shared global model.  The same body, shard_mapped over
+    the mesh data axis, is the sharded cohort engine
+    (repro/launch/cohort.py).
 
     run(params_global, batches, mus, corrections) with a leading cohort axis
     on ``batches`` (k, n_steps, batch, ...), ``mus`` (k,), ``corrections``
@@ -105,11 +130,8 @@ def make_cohort_trainer(loss_fn: Callable, opt: Optimizer, pspace: ParamSpace) -
     # (``jit_cohort_train``), which the benchmark reads by name
     @jax.jit
     def cohort_train(params_global, batches, mus, corrections) -> CohortResult:
-        res = jax.vmap(lambda b, m, c: single(params_global, b, m, c))(
-            batches, mus, corrections
-        )
-        return CohortResult(pspace.stack(res.delta), res.n_steps,
-                            res.loss_first, res.loss_last)
+        return map_cohort(lambda b, m, c: single(params_global, b, m, c), pspace,
+                          batches, mus, corrections)
 
     return cohort_train
 
@@ -120,8 +142,8 @@ def make_gossip_cohort_trainer(loss_fn: Callable, opt: Optimizer, pspace: ParamS
     Identical contract to :func:`make_cohort_trainer` except the cohort does
     NOT share one global model — each node trains from its own model, handed
     in as a ``(k, P)`` ParamSpace rows matrix (the representation the gossip
-    mixing passes operate on).  The rows are folded back to pytrees inside
-    the vmapped trace, so per-node param pytrees never exist outside jit.
+    mixing passes operate on).  Each node's row is folded back to a pytree
+    inside the mapped body, so per-node param pytrees never exist outside jit.
 
     When every row is identical this reduces to :func:`make_cohort_trainer`
     on that model — the training half of the gossip↔FedAvg equivalence
@@ -131,11 +153,8 @@ def make_gossip_cohort_trainer(loss_fn: Callable, opt: Optimizer, pspace: ParamS
 
     @jax.jit
     def cohort_train_rows(param_rows, batches, mus, corrections) -> CohortResult:
-        res = jax.vmap(lambda r, b, m, c: single(pspace.unravel(r), b, m, c))(
-            param_rows, batches, mus, corrections
-        )
-        return CohortResult(pspace.stack(res.delta), res.n_steps,
-                            res.loss_first, res.loss_last)
+        return map_cohort(lambda r, b, m, c: single(pspace.unravel(r), b, m, c), pspace,
+                          param_rows, batches, mus, corrections)
 
     return cohort_train_rows
 

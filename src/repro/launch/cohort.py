@@ -3,10 +3,11 @@ axis in ONE dispatch.
 
 Every strategy drives local training through a cohort trainer that returns
 ``(k, P)`` ParamSpace rows; ``TrainingConfig(sharded=True)`` makes
-``RuntimeContext`` build it here.  On one device that trainer vmaps the k
-clients; this module wraps the *same vmapped body* in ``jax.shard_map``
-over the ``data`` axis of the mesh (``cohort_mesh``) so each device trains
-k/d clients and the cohort's rows are reduced across devices in-graph:
+``RuntimeContext`` build it here.  On one device that trainer maps over
+the k clients, one after another (``client.map_cohort``); this module wraps
+the *same mapped body* in ``jax.shard_map`` over the ``data`` axis of the
+mesh (``cohort_mesh``) so each device trains its k/d clients in turn and
+the cohort's rows are reduced across devices in-graph:
 
   * :func:`make_sharded_cohort_trainer` — drop-in replacement for
     ``client.make_cohort_trainer``: all-gathers the per-device row shards so
@@ -83,15 +84,10 @@ def make_sharded_cohort_trainer(
     single = client_mod.make_local_trainer(loss_fn, opt)
 
     def shard_body(params_global, batches, mus, corrections) -> client_mod.CohortResult:
-        res = jax.vmap(lambda b, m, c: single(params_global, b, m, c))(
-            batches, mus, corrections
-        )
-        rows = pspace.stack(res.delta)  # (k_local, P)
+        res = client_mod.map_cohort(lambda b, m, c: single(params_global, b, m, c), pspace,
+                                    batches, mus, corrections)  # (k_local, P) rows
         gather = lambda x: jax.lax.all_gather(x, "data", axis=0, tiled=True)
-        return client_mod.CohortResult(
-            gather(rows), gather(res.n_steps),
-            gather(res.loss_first), gather(res.loss_last),
-        )
+        return client_mod.CohortResult(*map(gather, res))
 
     sharded = jax.shard_map(
         shard_body, mesh=mesh,
@@ -146,11 +142,9 @@ def make_sharded_cohort_step(
     single = client_mod.make_local_trainer(loss_fn, opt)
 
     def shard_body(params_global, batches, mus, corrections, weights):
-        res = jax.vmap(lambda b, m, c: single(params_global, b, m, c))(
-            batches, mus, corrections
-        )
-        rows = pspace.stack(res.delta)                   # (k_local, P)
-        part = jnp.einsum("kp,k->p", rows, weights)      # local partial reduce
+        res = client_mod.map_cohort(lambda b, m, c: single(params_global, b, m, c), pspace,
+                                    batches, mus, corrections)
+        part = jnp.einsum("kp,k->p", res.rows, weights)  # local partial reduce
         row = jax.lax.psum(part, "data")                 # cross-device reduce
         loss_last = jax.lax.all_gather(res.loss_last, "data", axis=0, tiled=True)
         return row, loss_last

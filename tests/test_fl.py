@@ -1,4 +1,6 @@
 """FL engine: algorithms, aggregation equivalences, end-to-end mini-simulation."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +11,9 @@ from repro.data.pipeline import build_clients
 from repro.data.synthetic import MNIST_LIKE, make_image_dataset
 from repro.fl import client as client_mod
 from repro.fl import server as server_mod
+from repro.fl.paramspace import ParamSpace
 from repro.fl.simulation import FLConfig, Simulation
+from repro.launch import cohort as cohort_mod
 from repro.models.resnet import ResNetConfig, init_resnet, resnet_loss
 from repro.optim import optimizers as opt_mod
 from repro.utils import tree_ravel
@@ -61,6 +65,63 @@ def test_fedprox_mu_shrinks_delta():
     n0 = float(jnp.linalg.norm(tree_ravel(d0)[0]))
     n1 = float(jnp.linalg.norm(tree_ravel(d1)[0]))
     assert n1 < n0  # strong proximal pull keeps w near w_t (Eq. 7)
+
+
+def _cohort_inputs(clients, params, k=3, steps=2, batch=8):
+    """k clients' stacked batches, distinct FedProx mus and small distinct
+    SCAFFOLD-style corrections, so every per-client input matters."""
+    batches = [clients[i].stacked_steps(batch, steps, 0) for i in range(k)]
+    batches = {key: jnp.stack([jnp.asarray(b[key]) for b in batches]) for key in batches[0]}
+    mus = jnp.asarray([0.0, 0.01, 0.1][:k], jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(5), len(jax.tree.leaves(params)))
+    corrections = jax.tree.unflatten(jax.tree.structure(params), [
+        1e-3 * jax.random.normal(kk, (k,) + x.shape) for kk, x in zip(keys, jax.tree.leaves(params))])
+    return batches, mus, corrections
+
+
+@pytest.mark.parametrize("kind", ["sync", "gossip_rows", "sharded_one_device"])
+def test_cohort_trainer_is_dense_and_matches_a_client_loop(kind):
+    """Each cohort trainer trains its clients one after another, so the
+    program holds dense convolutions only (a vmap over k weight copies
+    makes grouped ones), and each client's row and losses are those of the
+    single-client trainer run on its own: the same float32 ops, so only
+    the summation order may differ (atol 1e-6 on rows of at most 0.11;
+    the gaps read 3e-8)."""
+    _, clients, params, loss_fn, _ = _setup()
+    opt = opt_mod.momentum(0.05, beta=0.9)
+    pspace = ParamSpace.build(params)
+    batches, mus, corrections = _cohort_inputs(clients, params)
+    k = mus.shape[0]
+    if kind == "gossip_rows":
+        # every node starts from a model of its own
+        base = pspace.ravel(params)
+        first = base + 1e-3 * jax.random.normal(jax.random.PRNGKey(9), (k, pspace.dim))
+        trainer = client_mod.make_gossip_cohort_trainer(loss_fn, opt, pspace)
+        starts = [pspace.unravel(r) for r in first]
+    else:
+        first = params
+        trainer = (client_mod.make_cohort_trainer(loss_fn, opt, pspace) if kind == "sync" else
+                   cohort_mod.make_sharded_cohort_trainer(loss_fn, opt, pspace,
+                                                          mesh=cohort_mod.cohort_mesh(1)))
+        starts = [params] * k
+    inputs = (first, batches, mus, corrections)
+
+    text = jax.jit(trainer).lower(*inputs).as_text()
+    assert text.count("stablehlo.convolution") > 0
+    groups = re.findall(r"(?:feature|batch)_group_count = (\d+)", text)
+    assert groups and all(int(g) == 1 for g in groups)
+
+    res = trainer(*inputs)
+    assert res.rows.shape == (k, pspace.dim) and res.loss_last.shape == (k,)
+    single = client_mod.make_local_trainer(loss_fn, opt)
+    for i in range(k):
+        one = single(starts[i], jax.tree.map(lambda x: x[i], batches), mus[i],
+                     jax.tree.map(lambda x: x[i], corrections))
+        np.testing.assert_allclose(np.asarray(res.rows[i]), np.asarray(pspace.ravel(one.delta)),
+                                   rtol=0, atol=1e-6)
+        np.testing.assert_allclose(float(res.loss_first[i]), float(one.loss_first), rtol=0, atol=1e-6)
+        np.testing.assert_allclose(float(res.loss_last[i]), float(one.loss_last), rtol=0, atol=1e-6)
+        assert int(res.n_steps[i]) == int(one.n_steps)
 
 
 def test_weighted_mean_delta_weights():

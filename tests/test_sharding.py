@@ -125,7 +125,7 @@ def _cohort_setup(k=3, n_steps=2, batch=16):
 
 
 def test_sharded_cohort_trainer_matches_single_device():
-    """The shard_map trainer (1-device fallback mesh) reproduces the vmapped
+    """The shard_map trainer (1-device fallback mesh) reproduces the
     single-device cohort trainer — the smoke-protocol equivalence anchor."""
     from repro.fl import client as client_mod
     from repro.launch import cohort as cohort_mod

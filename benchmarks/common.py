@@ -92,7 +92,7 @@ def run_variant(name: str, dataset: str, seed: int = 0, rounds: int = ROUNDS,
         privacy=api.PrivacyConfig(secure_agg=secure_agg and algorithm != "fednova"),
         orchestrator=api.OrchestratorConfig(selection=kw.pop("selection")),
     )
-    if kw:  # FLConfig(**kw) used to reject these; don't silently drop them
+    if kw:  # don't silently drop keys the config has no field for
         raise TypeError(f"unmapped variant keys for {name!r}: {sorted(kw)}")
     task = api.FederatedTask(loss_fn, eval_fn, params, clients, data["test"])
     fed = api.build(cfg.to_dict(), task)  # round-trips the JSON-grid path

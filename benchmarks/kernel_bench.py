@@ -37,10 +37,16 @@ from repro.topo import graph as topo_graph
 RECORDS: list[dict] = []
 
 
+def _kernel_interpret() -> bool:
+    """The ``interpret`` flag that times the Pallas kernel itself: off the
+    TPU the wrappers would otherwise run the XLA reference."""
+    return ops.default_interpret()
+
+
 def _backend(kernel: bool) -> str:
     base = jax.default_backend()
     if kernel:
-        mode = "pallas-interpret" if ops.default_interpret() else "pallas-mosaic"
+        mode = "pallas-interpret" if _kernel_interpret() else "pallas-mosaic"
         return f"{base}:{mode}"
     return f"{base}:xla-ref"
 
@@ -92,10 +98,13 @@ def bench_flash(B=1, T=512, H=4, K=2, hd=64, block=128):
     q = jax.random.normal(ks[0], (B, T, H, hd))
     k = jax.random.normal(ks[1], (B, T, K, hd))
     v = jax.random.normal(ks[2], (B, T, K, hd))
-    out = ops.flash_attention(q, k, v, causal=True, block_q=block, block_k=block)
+    interp = _kernel_interpret()
+    out = ops.flash_attention(q, k, v, causal=True, block_q=block, block_k=block,
+                              interpret=interp)
     expect = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=5e-5, rtol=5e-5)
-    us_k = _time(lambda: ops.flash_attention(q, k, v, causal=True, block_q=block, block_k=block))
+    us_k = _time(lambda: ops.flash_attention(q, k, v, causal=True, block_q=block, block_k=block,
+                                             interpret=interp))
     us_r = _time(lambda: ref.flash_attention_ref(q, k, v, causal=True))
     vmem_kib = (block * 128 * 4 * 2 + 2 * block * 128 * 4 + block * (128 + 2) * 4) / 1024
     flops = 4 * B * H * T * T * hd / 2  # causal
@@ -117,10 +126,11 @@ def bench_masked_agg(n=16, P=262144, bits=16):
     qs = quantize.encode(pspace.pad_rows(ups), 1.0, bits)
     masks = secure_agg.mask_rows(jax.random.PRNGKey(7), n, pspace.padded_dim)
     masked = qs + masks
-    out = ops.masked_aggregate(masked, masks, 1.0, bits)
+    interp = _kernel_interpret()
+    out = ops.masked_aggregate(masked, masks, 1.0, bits, interpret=interp)
     expect = ref.masked_aggregate_ref(masked, masks, 1.0, bits)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=1e-6)
-    us_k = _time(lambda: ops.masked_aggregate(masked, masks, 1.0, bits))
+    us_k = _time(lambda: ops.masked_aggregate(masked, masks, 1.0, bits, interpret=interp))
     us_r = _time(lambda: ref.masked_aggregate_ref(masked, masks, 1.0, bits))
     Pp = pspace.padded_dim
     bytes_moved = 2 * n * Pp * 4 + Pp * 4
@@ -139,11 +149,12 @@ def bench_staleness_agg(k=16, P=262144):
     rng = np.random.default_rng(1)
     taus = rng.integers(0, 8, k)
     weights = jnp.asarray((1.0 / np.sqrt(1.0 + taus)).astype(np.float32))
-    out = ops.staleness_aggregate(deltas, weights)
+    interp = _kernel_interpret()
+    out = ops.staleness_aggregate(deltas, weights, interpret=interp)
     expect = ref.staleness_aggregate_ref(deltas, weights)
     err = float(np.max(np.abs(np.asarray(out) - np.asarray(expect))))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=1e-5)
-    us_k = _time(lambda: ops.staleness_aggregate(deltas, weights))
+    us_k = _time(lambda: ops.staleness_aggregate(deltas, weights, interpret=interp))
     us_r = _time(lambda: ref.staleness_aggregate_ref(deltas, weights))
     Pp = pspace.padded_dim
     bytes_moved = k * Pp * 4 + Pp * 4
@@ -165,11 +176,12 @@ def bench_gossip_mix(k=16, P=262144, graph="torus"):
     pspace = _row_space(P, seed=k)
     rows_x = pspace.pad_rows(_stacked_rows(pspace, k, seed=2))
     W = jnp.asarray(topo_graph.plan(graph, k, seed=0).mixing)
-    out = ops.gossip_mix(rows_x, W)
+    interp = _kernel_interpret()
+    out = ops.gossip_mix(rows_x, W, interpret=interp)
     expect = ref.gossip_mix_ref(rows_x, W)
     # different accumulation order from the matmul oracle: a few float32 ulps
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=1e-6, atol=1e-6)
-    us_k = _time(lambda: ops.gossip_mix(rows_x, W))
+    us_k = _time(lambda: ops.gossip_mix(rows_x, W, interpret=interp))
     us_r = _time(lambda: ref.gossip_mix_ref(rows_x, W))
     Pp = pspace.padded_dim
     bytes_moved = 2 * k * Pp * 4 + k * k * 4  # X read + written, W rides in VMEM

@@ -32,8 +32,6 @@ constructs it like a built-in.  ``strategy_names()`` lists what is
 registered; the built-in ``gossip`` strategy is itself registered this way.
 
 ``build(cfg_or_dict, task)`` is the registry constructor for JSON grids.
-The legacy ``FLConfig``/``Simulation`` entry points survive as deprecation
-shims over this package (see the README migration table).
 """
 from repro.api.config import (CarbonConfig, CheckpointConfig, EngineConfig,
                               ExperimentConfig, OrchestratorConfig,

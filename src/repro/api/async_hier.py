@@ -1,12 +1,11 @@
 """Asynchronous + hierarchical strategy (FedBuff-style buffered aggregation).
 
-The event-driven engine from the async runtime PR, lifted out of the legacy
-engine-subclass inheritance chain: it now *composes* the
-shared :class:`~repro.api.runtime.RuntimeContext` (same cohort trainer, same
+An event-driven strategy that composes the shared
+:class:`~repro.api.runtime.RuntimeContext` (same cohort trainer, same
 privacy pipeline, same server optimizer as the sync strategy) and plugs into
 :class:`~repro.api.federation.Federation` through the ``Strategy`` protocol.
 
-Behavior (unchanged from the engine it replaces):
+Behavior:
 
   * **Buffered async aggregation** — each region's edge aggregator applies an
     update whenever K client deltas have arrived, each delta down-weighted by
@@ -58,7 +57,6 @@ from repro.core import carbon as carbon_mod
 from repro.core import orchestrator as orch
 from repro.engine.clock import SimClock
 from repro.engine.events import EventQueue
-from repro.fl import client as client_mod
 from repro.fl import hierarchy
 from repro.privacy import dp as dp_mod
 from repro.privacy.accountant import SubsampledAccountant
@@ -259,8 +257,8 @@ class AsyncHierStrategy:
             sel_local = np.flatnonzero(np.asarray(mask))[:k]
             sel_global = reg.global_ids(sel_local)
 
-        with ctx.tracer.span("train", region=reg.idx, wave=reg.waves, cohort=len(sel_global),
-                             clients_per_pass=client_mod.CLIENTS_PER_PASS):
+        with ctx.tracer.span("train", region=reg.idx, wave=reg.waves,
+                             cohort=len(sel_global)):
             res = ctx.train_cohort(reg.edge_params, sel_global, reg.waves)
 
         if ctx.engine is not None:

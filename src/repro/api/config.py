@@ -1,9 +1,6 @@
 """Structured experiment configuration for the public ``repro.api``.
 
-The legacy engine exposed one flat 20-field ``FLConfig`` (plus an
-``AsyncFLConfig`` subclass) — every scenario axis lived in the same
-namespace, and composing a new experiment meant editing engine internals.
-Here each subsystem owns its own config block:
+Each subsystem owns its own config block:
 
     TrainingConfig      local/server optimization protocol (§IV)
     PrivacyConfig       clip→quantize→mask→noise pipeline + accounting
@@ -14,9 +11,7 @@ Here each subsystem owns its own config block:
     EngineConfig        continuous-time engine: trace-driven simulated clock
 
 ``ExperimentConfig`` composes the blocks and round-trips through plain
-dicts (``to_dict``/``from_dict``) so experiment grids can live in JSON.  The
-deprecated ``FLConfig`` shim (``repro.fl.simulation``) maps its flat fields
-onto these blocks 1:1 — see the README migration table.
+dicts (``to_dict``/``from_dict``) so experiment grids can live in JSON.
 """
 from __future__ import annotations
 

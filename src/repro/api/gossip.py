@@ -47,7 +47,6 @@ from repro.api.config import ExperimentConfig
 from repro.api.runtime import RuntimeContext
 from repro.api.telemetry import GOSSIP_HISTORY_KEYS, MixEvent
 from repro.core import carbon as carbon_mod
-from repro.fl import client as client_mod
 from repro.topo import gossip as gossip_mod
 from repro.topo import graph as graph_mod
 
@@ -192,8 +191,7 @@ class GossipStrategy:
                 k = len(sel)
 
                 # --- local training: each node from its own model row ----------
-                with tracer.span("train", round=rnd, cohort=k,
-                                 clients_per_pass=client_mod.CLIENTS_PER_PASS):
+                with tracer.span("train", round=rnd, cohort=k):
                     res = ctx.train_cohort_rows(self.node_rows[sel_ix], sel, rnd)
                     losses = [float(l) for l in res.loss_last]
                     rows = self.node_rows[sel_ix] + res.rows

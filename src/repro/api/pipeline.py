@@ -1,9 +1,7 @@
 """Composable row-native privacy pipeline (paper §III-C).
 
-The legacy engines hard-coded one aggregation chain in
-``Simulation._aggregate`` (clip → quantize → mask → kernel-sum → noise) with
-the composition decided by two config flags.  Here the chain is a
-:class:`PrivacyPipeline` of explicit stages over ``ParamSpace`` rows:
+The aggregation chain is a :class:`PrivacyPipeline` of explicit stages
+over ``ParamSpace`` rows:
 
     TopKStage      error-feedback top-k sparsification             [rows]
     ClipStage      per-client L2 clip (DP sensitivity bound)       [rows]
@@ -30,9 +28,9 @@ exactly what ran — the per-region DP accounting is driven entirely by the
 ``NoiseStage`` records.
 
 ``build_pipeline`` maps a :class:`~repro.api.config.PrivacyConfig` onto the
-three canonical compositions (plain / secure-agg / DP), reproducing the
-legacy chains (bit-for-bit unfused; fused, the DP ciphertexts within one
-quantization step); hand-compose stages for anything else, e.g.
+three canonical compositions (plain / secure-agg / DP; fused, the DP
+ciphertexts match the staged chain's within one quantization step);
+hand-compose stages for anything else, e.g.
 central DP without masking::
 
     PrivacyPipeline((ClipStage(1.0), NoiseStage(dp_cfg)), weighting="uniform")
@@ -422,9 +420,7 @@ def cohort_wire_bytes(records, cohort: int, model_bytes: float, dim: int) -> flo
 
 
 def build_pipeline(privacy) -> PrivacyPipeline:
-    """Map a ``PrivacyConfig`` onto the canonical stage compositions.
-
-    Reproduces the legacy ``Simulation._aggregate`` chains exactly:
+    """Map a ``PrivacyConfig`` onto the canonical stage compositions:
 
         dp set       : [topk →] clip → quantize → mask → [kernel sum] → noise, /k
         secure_agg   : [topk →] scale → quantize → mask → [kernel sum], /k

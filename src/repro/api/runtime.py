@@ -3,11 +3,9 @@
 ``RuntimeContext`` wires the subsystem stack once — data-size weights, the
 flat-row ``ParamSpace``, the (optionally sharded) cohort trainer, the server
 optimizer, the provider fleet + carbon model, the selection policy/MARL
-state, and the privacy pipeline — and both strategies (the synchronous round
-loop and the event-driven async hierarchy) drive it.  This replaces the old
-arrangement where the async engine *inherited* the sync ``Simulation`` to
-reach its setup code: strategies now compose a context instead of
-subclassing an engine.
+state, and the privacy pipeline — and every strategy (the synchronous round
+loop, the event-driven async hierarchy, gossip) composes it rather than
+subclassing another strategy.
 
 Dataflow is flat-row end to end (``repro.fl.paramspace``): the cohort
 trainer returns (k, P) float32 delta rows, the privacy pipeline
@@ -321,18 +319,14 @@ class RuntimeContext:
     def weighted_sum(self, rows: jax.Array, w) -> jax.Array:
         """Σ_i w_i·row_i — the shared sync/async server reduction.
 
-        On TPU this is the fused Pallas buffer-aggregation kernel (one VMEM
-        pass over the (k, P) rows, pre-padded to whole blocks by the
-        ParamSpace); on CPU the Pallas interpreter would be strictly slower
-        than XLA, so a single einsum over the rows stays the hot path there.
-        Both strategies route through this method, which is what makes the
-        async sync-equivalence anchor bitwise.
+        The fused buffer-aggregation kernel (one VMEM pass over the (k, P)
+        rows, pre-padded to whole blocks by the ParamSpace); off the TPU
+        ``kernels.ops`` runs its XLA reference instead.  Both strategies
+        route through this method, which is what makes the async
+        sync-equivalence anchor bitwise.
         """
         w = jnp.asarray(w, jnp.float32)
-        if kernel_ops.default_interpret():
-            return jnp.einsum("kp,k->p", rows, w)
-        out = kernel_ops.staleness_aggregate(self.pspace.pad_rows(rows), w)
-        return out[: self.pspace.dim]
+        return kernel_ops.staleness_aggregate(self.pspace.pad_rows(rows), w)[: self.pspace.dim]
 
     # ------------------------------------------------------------------
     def round_accounting(self, sel, t_hours: float):

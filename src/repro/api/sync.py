@@ -5,10 +5,8 @@ one cohort-training dispatch, the privacy pipeline, one server
 update, then emissions accounting and the MARL reward — emitting one typed
 :class:`~repro.api.telemetry.RoundEvent` per round.
 
-This is the former ``Simulation.run`` loop lifted out of the monolithic
-engine class: the subsystem wiring lives in
-:class:`~repro.api.runtime.RuntimeContext`, and the asynchronous strategy
-composes the same context instead of subclassing this one.
+The subsystem wiring lives in :class:`~repro.api.runtime.RuntimeContext`,
+which the asynchronous and gossip strategies compose too.
 """
 from __future__ import annotations
 
@@ -140,8 +138,7 @@ class SyncStrategy:
                     )
                 else:
                     corrs = None  # train_cohort broadcasts the zero correction
-                with tracer.span("train", round=rnd, cohort=len(sel),
-                                 clients_per_pass=client_mod.CLIENTS_PER_PASS):
+                with tracer.span("train", round=rnd, cohort=len(sel)):
                     res = ctx.train_cohort(
                         ctx.server_state.params, sel, rnd, corrections=corrs
                     )

@@ -86,11 +86,6 @@ def make_local_trainer(loss_fn: Callable, opt: Optimizer) -> Callable:
     return run
 
 
-# clients that share one pass of the model on the device in the cohort
-# trainers: :func:`map_cohort` trains them one at a time
-CLIENTS_PER_PASS = 1
-
-
 def map_cohort(train_one: Callable, pspace: ParamSpace, *cohort) -> CohortResult:
     """Train a cohort one client after another inside the caller's jitted
     program: ``jax.lax.map`` over the leading (k,) axis of ``cohort``.

@@ -8,11 +8,8 @@ clients is a ``(k, dim)`` **rows** matrix.  This module is the only place in
 ``repro.fl`` / ``repro.privacy`` where pytrees are flattened or rows are
 folded back into pytrees — the single conversion site.
 
-Why it exists: before this refactor the codebase re-flattened pytrees in
-four places (``Simulation._stack_rows``/``_unstack_rows``, ``tree_ravel`` in
-``utils.py`` and ``privacy/dp.py``, per-leaf einsums), each with its own
-ravel order and dtype rules.  A ``ParamSpace`` is built once from
-``params0`` and owns:
+Flattening in one place keeps one ravel order and one set of dtype rules.
+A ``ParamSpace`` is built once from ``params0`` and owns:
 
   * the treedef + per-leaf shapes/dtypes/sizes/offsets (ravel order),
   * ``dim`` (P, the flat parameter count) and ``padded_dim`` (P rounded up

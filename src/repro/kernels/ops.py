@@ -2,9 +2,15 @@
 
 Shape plumbing: (B, T, H, hd) model-layout attention -> (B*H, T, hd) kernel
 layout, GQA head mapping, head-dim padding to the 128-lane MXU width, and
-sequence padding to block multiples.  ``interpret`` defaults to None, which
-resolves per-backend: interpreter on CPU (this container), Mosaic lowering
-on TPU.  Pass an explicit bool to override.
+sequence padding to block multiples.
+
+Every wrapper picks its backend by one rule (:func:`_dispatch`):
+``interpret=None`` runs the Mosaic kernel on a TPU and the XLA reference of
+``kernels/ref.py`` anywhere else (the Pallas interpreter is far slower than
+XLA off the TPU); ``interpret=True`` forces the Pallas interpreter and
+``False`` forces Mosaic.  The reference and the kernel are different
+programs, so they agree to within rounding (one quantization step for the
+ring encoder), not bitwise.
 
 Every kernel dispatch runs under a ``jax.named_scope`` (``repro.kernels/*``).
 On the TPU the profiler's operation events carry no scope; what the scope
@@ -34,8 +40,15 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _resolve(interpret: Optional[bool]) -> bool:
-    return default_interpret() if interpret is None else interpret
+def _dispatch(kernel, reference, interpret: Optional[bool]):
+    """The backend rule of every wrapper: ``reference()`` where
+    ``interpret`` is None off the TPU, else ``kernel(interpret=...)`` with
+    None resolved to Mosaic."""
+    if interpret is None:
+        if default_interpret():
+            return reference()
+        interpret = False
+    return kernel(interpret=interpret)
 
 
 @functools.partial(
@@ -48,6 +61,17 @@ def flash_attention(
     interpret: Optional[bool] = None,
 ):
     """Flash attention with GQA. q: (B, T, H, hd); k, v: (B, S, K, hd)."""
+    return _dispatch(
+        functools.partial(_flash_attention_kernel, q, k, v, causal=causal, window=window,
+                          logit_cap=logit_cap, block_q=block_q, block_k=block_k),
+        functools.partial(ref_mod.flash_attention_ref, q, k, v, causal=causal,
+                          window=window, logit_cap=logit_cap),
+        interpret,
+    )
+
+
+def _flash_attention_kernel(q, k, v, *, causal, window, logit_cap, block_q, block_k,
+                            interpret):
     B, T, H, hd = q.shape
     S, K = k.shape[1], k.shape[2]
     assert H % K == 0
@@ -69,8 +93,7 @@ def flash_attention(
     with jax.named_scope("repro.kernels/flash_attention"):
         out = fa.flash_attention_bh(
             qbh, kbh, vbh, causal=causal, window=window, logit_cap=logit_cap,
-            block_q=block_q, block_k=block_k, group=group, seq_k=S,
-            interpret=_resolve(interpret),
+            block_q=block_q, block_k=block_k, group=group, seq_k=S, interpret=interpret,
         )
     out = out.reshape(B, H, T_p, hd_p).transpose(0, 2, 1, 3)
     return out[:, :T, :, :hd].astype(q.dtype)
@@ -87,8 +110,10 @@ def masked_aggregate(masked, masks, clip: float, bits: int, *, block_p: int = 20
     the hot path; arbitrary P still works for direct callers.
     """
     with jax.named_scope("repro.kernels/masked_agg"):
-        return ma.masked_aggregate(
-            masked, masks, clip, bits, block_p=block_p, interpret=_resolve(interpret)
+        return _dispatch(
+            functools.partial(ma.masked_aggregate, masked, masks, clip, bits, block_p=block_p),
+            functools.partial(ref_mod.masked_aggregate_ref, masked, masks, clip, bits),
+            interpret,
         )
 
 
@@ -98,22 +123,16 @@ def clip_quant_mask(rows, masks, clip: float, bits: int, *, dim: Optional[int] =
     """Fused delta-to-wire compression: a norm pass, then clip + quantize +
     mask in one P-tiled kernel pass (see compress.py).  rows (k, P) float32,
     masks (k, P) uint32 -> (k, P) uint32 ciphertext; ``dim`` bounds the L2
-    norm to the unpadded columns.
-
-    Dispatch mirrors ``RuntimeContext.weighted_sum``: on TPU the Pallas
-    kernel runs (Mosaic lowering); on CPU the interpreter would be strictly
-    slower than XLA, so ``interpret=None`` routes to the *same fused math*
-    as one XLA expression (``ref.clip_quant_mask_ref``).  The paths are
-    different programs, so their ciphertexts agree to within one
-    quantization step, not bitwise (tests/test_kernels.py pins this).
-    Pass ``interpret=True`` to force the Pallas interpreter.
+    norm to the unpadded columns.  The kernel's and the reference's
+    ciphertexts agree to within one quantization step (tests/test_kernels.py
+    pins this).
     """
     with jax.named_scope("repro.kernels/clip_quant_mask"):
-        if interpret is None and default_interpret():
-            return ref_mod.clip_quant_mask_ref(rows, masks, clip, bits, dim=dim)
-        return cp.clip_quant_mask(
-            rows, masks, clip, bits, dim=dim, block_p=block_p,
-            interpret=_resolve(interpret),
+        return _dispatch(
+            functools.partial(cp.clip_quant_mask, rows, masks, clip, bits, dim=dim,
+                              block_p=block_p),
+            functools.partial(ref_mod.clip_quant_mask_ref, rows, masks, clip, bits, dim=dim),
+            interpret,
         )
 
 
@@ -127,8 +146,10 @@ def staleness_aggregate(deltas, weights, *, block_p: int = 2048,
     rows to whole blocks so no reshaping or padding happens here.
     """
     with jax.named_scope("repro.kernels/staleness_agg"):
-        return sa.staleness_aggregate(
-            deltas, weights, block_p=block_p, interpret=_resolve(interpret)
+        return _dispatch(
+            functools.partial(sa.staleness_aggregate, deltas, weights, block_p=block_p),
+            functools.partial(ref_mod.staleness_aggregate_ref, deltas, weights),
+            interpret,
         )
 
 
@@ -143,4 +164,8 @@ def gossip_mix(rows, mixing, *, block_p: int = 2048,
     the hot path; arbitrary P still works for direct callers.
     """
     with jax.named_scope("repro.kernels/gossip_mix"):
-        return gm.gossip_mix(rows, mixing, block_p=block_p, interpret=_resolve(interpret))
+        return _dispatch(
+            functools.partial(gm.gossip_mix, rows, mixing, block_p=block_p),
+            functools.partial(ref_mod.gossip_mix_ref, rows, mixing),
+            interpret,
+        )

@@ -5,11 +5,10 @@ of its neighborhood:
 
     X ← W X,        X: (k, P) ParamSpace rows,  W: (k, k) mixing matrix
 
-On TPU this is the fused Pallas ``gossip_mix`` kernel — neighbor gather +
+This is the fused Pallas ``gossip_mix`` kernel — neighbor gather +
 weighted combine over (k, block_p) row tiles in a single VMEM pass
-(``repro.kernels.gossip_mix``); on CPU the interpreter would be strictly
-slower than XLA, so the einsum reference stays the hot path, mirroring
-``RuntimeContext.weighted_sum``.
+(``repro.kernels.gossip_mix``); off the TPU ``kernels.ops`` runs its XLA
+reference instead, as for ``RuntimeContext.weighted_sum``.
 
 Also here: the optional carbon-aware neighbor reweighting (low-intensity
 peers weighted up, ``carbon_reweight``) and the consensus-distance
@@ -23,23 +22,17 @@ import numpy as np
 
 from repro.fl.paramspace import ParamSpace
 from repro.kernels import ops as kernel_ops
-from repro.kernels import ref as kernel_ref
 
 __all__ = ["carbon_reweight", "consensus_distance", "mix_rows"]
 
 
 def mix_rows(pspace: ParamSpace, rows: jax.Array, mixing: jax.Array) -> jax.Array:
-    """One gossip pass X ← W X over (k, P) ParamSpace rows.
-
-    Backend-dispatched like the server reductions: the Pallas kernel on TPU
-    (rows pre-padded to whole VMEM blocks), the einsum oracle on CPU.  Both
-    paths are checked against each other (allclose) in ``tests/test_topo.py``.
+    """One gossip pass X ← W X over (k, P) ParamSpace rows, pre-padded to
+    whole VMEM blocks for the kernel.  The kernel and its reference are
+    checked against each other (allclose) in ``tests/test_topo.py``.
     """
     W = jnp.asarray(mixing, jnp.float32)
-    if kernel_ops.default_interpret():
-        return kernel_ref.gossip_mix_ref(rows, W)
-    out = kernel_ops.gossip_mix(pspace.pad_rows(rows), W)
-    return out[:, : pspace.dim]
+    return kernel_ops.gossip_mix(pspace.pad_rows(rows), W)[:, : pspace.dim]
 
 
 def carbon_reweight(mixing: np.ndarray, intensities: np.ndarray, beta: float) -> np.ndarray:

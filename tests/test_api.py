@@ -1,9 +1,8 @@
-"""repro.api: Federation golden-equivalence vs the legacy shims, config
+"""repro.api: the JSON-grid ``build`` path against ``Federation``, config
 round-trip, privacy-pipeline stages, per-region accountant, telemetry, and
 the stale-in-state MARL encoding."""
 import io
 import json
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -37,75 +36,41 @@ _BASE = dict(n_clients=6, clients_per_round=3, rounds=2, local_steps=2,
              batch_size=16, eval_every=1, seed=3)
 
 
-def _legacy_sync(privacy_kw, **base):
-    from repro.fl.simulation import FLConfig, Simulation
-
-    data, clients, params, loss_fn, eval_fn, _ = _setup()
-    with pytest.warns(DeprecationWarning):
-        sim = Simulation(FLConfig(**base, **privacy_kw), loss_fn, eval_fn,
-                         params, clients, data["test"])
-    return sim.run()
-
-
 # ---------------------------------------------------------------------------
-# Golden equivalence: Federation runs must reproduce the legacy constructors.
-#
-# What these pin: the FLConfig->ExperimentConfig field mapping, the shim's
-# delegation, and the history-dict schema (the legacy names now route through
-# Federation, so both sides share the engine).  The *behavioral* anchors that
-# guard the engine itself are test_async.py::test_sync_equivalence* (async
-# degenerates to sync), test_fl.py::test_secure_agg_matches_plain_aggregation,
-# and test_sharding.py's flat-vs-sharded allclose — all unchanged from the
-# pre-API engines and still passing, which is what certifies the rewrite.
+# The JSON-grid path: ``build(cfg.to_dict(), task)`` drives the same run as
+# ``Federation(cfg, task)`` and gives the same history schema.
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("privacy_cfg,legacy_kw", [
-    (api.PrivacyConfig(), {}),
-    (api.PrivacyConfig(secure_agg=True, sa_bits=24),
-     dict(secure_agg=True, sa_bits=24)),
-    (api.PrivacyConfig(dp=DPConfig(clip=2.0, sigma=1.1, sample_rate=0.5, rounds=2)),
-     dict(dp=DPConfig(clip=2.0, sigma=1.1, sample_rate=0.5, rounds=2))),
-], ids=["plain", "secure_agg", "dp"])
-def test_federation_sync_matches_legacy_simulation(privacy_cfg, legacy_kw):
+@pytest.mark.parametrize("cfg", [
+    api.ExperimentConfig(training=api.TrainingConfig(**_BASE)),
+    api.ExperimentConfig(training=api.TrainingConfig(**_BASE),
+                         privacy=api.PrivacyConfig(secure_agg=True, sa_bits=24)),
+    api.ExperimentConfig(
+        training=api.TrainingConfig(**_BASE),
+        privacy=api.PrivacyConfig(dp=DPConfig(clip=2.0, sigma=1.1, sample_rate=0.5, rounds=2)),
+    ),
+    api.ExperimentConfig(
+        training=api.TrainingConfig(**dict(_BASE, rounds=4)),
+        topology=api.TopologyConfig(mode="async_hier", latency_spread=1.0, buffer_k=2,
+                                    concurrency=6, n_regions=2, edge_sync_every=2),
+    ),
+], ids=["plain", "secure_agg", "dp", "async_hier"])
+def test_build_from_dict_matches_federation(cfg):
     _, _, _, _, _, task = _setup()
-    cfg = api.ExperimentConfig(training=api.TrainingConfig(**_BASE), privacy=privacy_cfg)
-    h = api.build(cfg.to_dict(), task).run()  # exercises the JSON-grid path too
-    h_legacy = _legacy_sync(legacy_kw, **_BASE)
-    assert sorted(h) == sorted(h_legacy)  # byte-compatible history schema
-    np.testing.assert_allclose(h["acc"], h_legacy["acc"])
-    np.testing.assert_allclose(h["loss"], h_legacy["loss"])
-    np.testing.assert_allclose(h["cum_co2_g"], h_legacy["cum_co2_g"])
-    np.testing.assert_allclose(h["eps_spent"], h_legacy["eps_spent"])
-    assert h["selected"] == h_legacy["selected"]
-
-
-def test_federation_async_matches_legacy_async_engine():
-    from repro.fl.async_runtime import AsyncFLConfig, AsyncHierSimulation
-
-    data, clients, params, loss_fn, eval_fn, task = _setup()
-    base = dict(_BASE, rounds=4)
-    topo = dict(latency_spread=1.0, buffer_k=2, concurrency=6, n_regions=2,
-                edge_sync_every=2)
-    cfg = api.ExperimentConfig(
-        training=api.TrainingConfig(**base),
-        topology=api.TopologyConfig(mode="async_hier", **topo),
-    )
-    h = api.Federation(cfg, task).run()
-    with pytest.warns(DeprecationWarning):
-        sim = AsyncHierSimulation(AsyncFLConfig(**base, **topo), loss_fn, eval_fn,
-                                  params, clients, data["test"])
-    h_legacy = sim.run()
-    assert sorted(h) == sorted(h_legacy)
-    np.testing.assert_allclose(h["acc"], h_legacy["acc"])
-    np.testing.assert_allclose(h["loss"], h_legacy["loss"])
-    np.testing.assert_allclose(h["staleness"], h_legacy["staleness"])
-    assert h["region"] == h_legacy["region"]
-    assert h["selected"] == h_legacy["selected"]
-    assert h["buffer_flushes"] == h_legacy["buffer_flushes"]
-    # the shim exposes the legacy runtime-attribute surface
-    assert sim.buffer_k == 2 and sim.global_version >= 2
-    assert len(sim.regions) == 2 and sim.fleet.n == 6
+    fed = api.build(cfg.to_dict(), task)
+    h = fed.run()
+    h_direct = api.Federation(cfg, task).run()
+    assert sorted(h) == sorted(h_direct)
+    for key in ("acc", "loss", "cum_co2_g", "eps_spent", "staleness"):
+        if key in h_direct:
+            np.testing.assert_allclose(h[key], h_direct[key])
+    for key in ("selected", "region", "buffer_flushes"):
+        if key in h_direct:
+            assert h[key] == h_direct[key]
+    if cfg.topology.mode == "async_hier":
+        assert fed.strategy.buffer_k == 2 and fed.strategy.global_version >= 2
+        assert len(fed.strategy.regions) == 2 and fed.ctx.fleet.n == 6
 
 
 def test_async_strategy_rejects_sync_only_algorithms_via_api():
@@ -179,20 +144,6 @@ def test_experiment_config_round_trips_through_json():
     assert restored == cfg
     assert isinstance(restored.privacy.dp, DPConfig)
     assert api.ExperimentConfig.from_dict({}) == api.ExperimentConfig()
-
-
-def test_legacy_flconfig_maps_one_to_one():
-    from repro.fl.simulation import FLConfig, experiment_config
-
-    legacy = FLConfig(algorithm="fedadam", selection="green", n_clients=9,
-                      clients_per_round=4, rounds=3, secure_agg=True, sa_bits=18,
-                      round_hours=0.1, hetero=0.5, seed=4)
-    cfg = experiment_config(legacy)
-    assert cfg.training.algorithm == "fedadam" and cfg.training.n_clients == 9
-    assert cfg.orchestrator.selection == "green"
-    assert cfg.privacy.secure_agg and cfg.privacy.sa_bits == 18
-    assert cfg.carbon.round_hours == 0.1 and cfg.carbon.hetero == 0.5
-    assert cfg.topology.mode == "sync"
 
 
 # ---------------------------------------------------------------------------
@@ -503,26 +454,3 @@ def test_progress_callback_still_works_through_federation():
     rows = []
     api.Federation(cfg, task).run(progress=rows.append)
     assert len(rows) == 1 and set(rows[0]) == {"round", "acc", "co2_g", "loss"}
-
-
-# ---------------------------------------------------------------------------
-# Import-direction guard: internals must not construct via the legacy names
-# ---------------------------------------------------------------------------
-
-
-def test_internals_do_not_import_legacy_engine_names():
-    import pathlib
-
-    import repro
-
-    root = pathlib.Path(next(iter(repro.__path__)))  # namespace pkg: no __file__
-    shims = {root / "fl" / "simulation.py", root / "fl" / "async_runtime.py"}
-    offenders = []
-    for path in root.rglob("*.py"):
-        if path in shims:
-            continue
-        src = path.read_text()
-        if "fl.simulation import" in src or "fl.async_runtime import" in src \
-                or "fl import simulation" in src or "fl import async_runtime" in src:
-            offenders.append(str(path))
-    assert not offenders, f"internals import legacy engine names: {offenders}"

@@ -5,13 +5,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import api
 from repro.core import scheduler
 from repro.data.partition import dirichlet_partition
 from repro.data.pipeline import build_clients
 from repro.data.synthetic import MNIST_LIKE, make_image_dataset
-from repro.fl.async_runtime import AsyncFLConfig, AsyncHierSimulation
 from repro.fl.hierarchy import assign_regions, staleness_weight, subfleet
-from repro.fl.simulation import FLConfig, Simulation
 from repro.kernels import ops, ref
 from repro.models.resnet import ResNetConfig, init_resnet, resnet_loss
 
@@ -24,7 +23,16 @@ def _setup(n_clients=6, n_train=800, n_test=512):
     params = init_resnet(jax.random.PRNGKey(0), rcfg)
     loss_fn = lambda p, b: resnet_loss(p, rcfg, b)
     eval_fn = lambda p, b: resnet_loss(p, rcfg, b)[1]
-    return data, clients, params, loss_fn, eval_fn
+    return api.FederatedTask(loss_fn, eval_fn, params, clients, data["test"])
+
+
+def _config(mode="sync", selection="random", privacy=None, topology=None, **training):
+    return api.ExperimentConfig(
+        training=api.TrainingConfig(**training),
+        privacy=privacy or api.PrivacyConfig(),
+        topology=api.TopologyConfig(mode=mode, **(topology or {})),
+        orchestrator=api.OrchestratorConfig(selection=selection),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -38,7 +46,7 @@ def test_staleness_agg_kernel_matches_einsum(k, P):
     deltas = jnp.asarray(rng.normal(0, 0.1, (k, P)).astype(np.float32))
     taus = rng.integers(0, 8, k)
     weights = jnp.asarray(staleness_weight(taus).astype(np.float32))
-    out = ops.staleness_aggregate(deltas, weights)
+    out = ops.staleness_aggregate(deltas, weights, interpret=True)
     expect = ref.staleness_aggregate_ref(deltas, weights)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=1e-5, rtol=1e-5)
 
@@ -49,7 +57,7 @@ def test_staleness_agg_kernel_block_sizes():
     weights = jnp.asarray(rng.uniform(0.1, 1.0, 5).astype(np.float32))
     expect = ref.staleness_aggregate_ref(deltas, weights)
     for bp in (256, 1024, 4096):
-        out = ops.staleness_aggregate(deltas, weights, block_p=bp)
+        out = ops.staleness_aggregate(deltas, weights, block_p=bp, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=1e-5)
 
 
@@ -134,10 +142,14 @@ def test_topk_mask_exact_k_on_ties():
 # ---------------------------------------------------------------------------
 
 
-def _equiv_configs(**variant):
+def _equiv_runs(**variant):
     base = dict(n_clients=6, clients_per_round=3, rounds=4, local_steps=2,
                 batch_size=16, eval_every=2, seed=3, **variant)
-    return FLConfig(**base), AsyncFLConfig(latency_spread=0.0, **base)
+    task = _setup()
+    h_s = api.Federation(_config(**base), task).run()
+    h_a = api.Federation(_config(mode="async_hier", topology=dict(latency_spread=0.0), **base),
+                         task).run()
+    return h_s, h_a
 
 
 @pytest.mark.parametrize("variant", [
@@ -145,10 +157,7 @@ def _equiv_configs(**variant):
     dict(algorithm="fedadam", selection="rl_green", server_lr=0.02),
 ])
 def test_sync_equivalence(variant):
-    data, clients, params, loss_fn, eval_fn = _setup()
-    cfg_s, cfg_a = _equiv_configs(**variant)
-    h_s = Simulation(cfg_s, loss_fn, eval_fn, params, clients, data["test"]).run()
-    h_a = AsyncHierSimulation(cfg_a, loss_fn, eval_fn, params, clients, data["test"]).run()
+    h_s, h_a = _equiv_runs(**variant)
     assert abs(h_s["final_acc"] - h_a["final_acc"]) < 1e-3
     np.testing.assert_allclose(h_s["acc"], h_a["acc"], atol=1e-3)
     np.testing.assert_allclose(h_s["loss"], h_a["loss"], atol=1e-5)
@@ -158,11 +167,8 @@ def test_sync_equivalence(variant):
 
 def test_sync_equivalence_secure_agg():
     """1 region + masked-ring aggregation == the flat secure-agg engine."""
-    data, clients, params, loss_fn, eval_fn = _setup()
-    cfg_s, cfg_a = _equiv_configs(algorithm="fedavg", selection="random",
-                                  secure_agg=True, sa_bits=24)
-    h_s = Simulation(cfg_s, loss_fn, eval_fn, params, clients, data["test"]).run()
-    h_a = AsyncHierSimulation(cfg_a, loss_fn, eval_fn, params, clients, data["test"]).run()
+    h_s, h_a = _equiv_runs(algorithm="fedavg", selection="random",
+                           privacy=api.PrivacyConfig(secure_agg=True, sa_bits=24))
     assert abs(h_s["final_acc"] - h_a["final_acc"]) < 1e-3
     np.testing.assert_allclose(h_s["loss"], h_a["loss"], atol=1e-5)
 
@@ -174,12 +180,13 @@ def test_sync_equivalence_secure_agg():
 
 def test_async_staleness_emerges_with_overlap():
     """Double concurrency + small buffer: some deltas must arrive stale."""
-    data, clients, params, loss_fn, eval_fn = _setup()
-    cfg = AsyncFLConfig(algorithm="fedavg", selection="random", n_clients=6,
-                        clients_per_round=3, rounds=6, local_steps=2, batch_size=16,
-                        eval_every=3, seed=3, latency_spread=1.0, buffer_k=2,
-                        concurrency=6, n_regions=2, edge_sync_every=2)
-    h = AsyncHierSimulation(cfg, loss_fn, eval_fn, params, clients, data["test"]).run()
+    cfg = _config(mode="async_hier", selection="random", algorithm="fedavg", n_clients=6,
+                  clients_per_round=3, rounds=6, local_steps=2, batch_size=16,
+                  eval_every=3, seed=3,
+                  topology=dict(latency_spread=1.0, buffer_k=2, concurrency=6, n_regions=2,
+                                edge_sync_every=2))
+    task = _setup()
+    h = api.Federation(cfg, task).run()
     assert len(h["acc"]) == 6
     assert max(h["staleness"]) > 0.0
     assert sorted(h["buffer_flushes"]) == [0, 1]
@@ -190,8 +197,7 @@ def test_async_staleness_emerges_with_overlap():
     assert np.isfinite(h["final_acc"])
     # every flush stays inside its region's client set
     regions = assign_regions(
-        AsyncHierSimulation(cfg, loss_fn, eval_fn, params, clients, data["test"]).fleet,
-        cfg.n_regions,
+        api.Federation(cfg, task).ctx.fleet, cfg.topology.n_regions,
     )
     for rid, sel in zip(h["region"], h["selected"]):
         assert set(sel) <= set(regions[rid].tolist())
@@ -201,42 +207,43 @@ def test_global_staleness_version_accounting():
     """Multi-region runs interleave edge→global syncs: the server's round
     counter is exactly the global version, every region's last-sync marker
     trails it, and the straggler EMA picked up the emergent staleness."""
-    data, clients, params, loss_fn, eval_fn = _setup()
-    cfg = AsyncFLConfig(algorithm="fedavg", selection="rl_green", n_clients=6,
-                        clients_per_round=3, rounds=6, local_steps=2, batch_size=16,
-                        eval_every=3, seed=3, latency_spread=1.0, buffer_k=2,
-                        concurrency=6, n_regions=2, edge_sync_every=2)
-    sim = AsyncHierSimulation(cfg, loss_fn, eval_fn, params, clients, data["test"])
-    h = sim.run()
-    assert sim.global_version == int(sim.server_state.round)  # one bump per apply
-    assert sim.global_version >= 2  # both regions synced at least once
-    for reg in sim.regions:
-        assert 0 < reg.synced_version <= sim.global_version
+    cfg = _config(mode="async_hier", selection="rl_green", algorithm="fedavg", n_clients=6,
+                  clients_per_round=3, rounds=6, local_steps=2, batch_size=16,
+                  eval_every=3, seed=3,
+                  topology=dict(latency_spread=1.0, buffer_k=2, concurrency=6, n_regions=2,
+                                edge_sync_every=2))
+    fed = api.Federation(cfg, _setup())
+    h = fed.run()
+    strategy = fed.strategy
+    assert strategy.global_version == int(fed.ctx.server_state.round)  # one bump per apply
+    assert strategy.global_version >= 2  # both regions synced at least once
+    for reg in strategy.regions:
+        assert 0 < reg.synced_version <= strategy.global_version
     # overlap produced staleness, so some straggler EMA must be non-zero
     assert max(h["staleness"]) > 0.0
-    assert any(float(jnp.max(reg.orch_state.stale_ema)) > 0.0 for reg in sim.regions)
+    assert any(float(jnp.max(reg.orch_state.stale_ema)) > 0.0 for reg in strategy.regions)
 
 
 def test_async_multi_flush_per_wave_derives_fresh_keys():
     """buffer_k < wave size: one wave triggers several flushes, which must
     consume distinct mask/noise keys (fold_in per trigger) — the first flush
     keeps the wave key verbatim so the sync-equivalence anchor stays exact."""
-    data, clients, params, loss_fn, eval_fn = _setup(n_train=400, n_test=64)
-    cfg = AsyncFLConfig(algorithm="fedavg", selection="random", n_clients=6,
-                        clients_per_round=4, rounds=4, local_steps=2, batch_size=16,
-                        eval_every=4, seed=5, latency_spread=0.0, buffer_k=2,
-                        concurrency=4, secure_agg=True, sa_bits=24)
-    sim = AsyncHierSimulation(cfg, loss_fn, eval_fn, params, clients, data["test"])
-    h = sim.run()
+    cfg = _config(mode="async_hier", selection="random", algorithm="fedavg", n_clients=6,
+                  clients_per_round=4, rounds=4, local_steps=2, batch_size=16,
+                  eval_every=4, seed=5,
+                  privacy=api.PrivacyConfig(secure_agg=True, sa_bits=24),
+                  topology=dict(latency_spread=0.0, buffer_k=2, concurrency=4))
+    fed = api.Federation(cfg, _setup(n_train=400, n_test=64))
+    h = fed.run()
     assert len(h["acc"]) == 4
     # every wave of 4 produced exactly 2 flushes through the K=2 buffer
-    counts = list(sim.regions[0].wave_flushes.values())
+    counts = list(fed.strategy.regions[0].wave_flushes.values())
     assert counts and all(c == 2 for c in counts)
     assert np.isfinite(h["final_acc"])
 
 
 def test_async_rejects_sync_only_algorithms():
-    data, clients, params, loss_fn, eval_fn = _setup(n_train=200, n_test=64)
-    cfg = AsyncFLConfig(algorithm="scaffold", n_clients=6, clients_per_round=2, rounds=1)
+    cfg = _config(mode="async_hier", algorithm="scaffold", n_clients=6, clients_per_round=2,
+                  rounds=1)
     with pytest.raises(ValueError, match="scaffold"):
-        AsyncHierSimulation(cfg, loss_fn, eval_fn, params, clients, data["test"])
+        api.Federation(cfg, _setup(n_train=200, n_test=64))

@@ -6,13 +6,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import api
 from repro.data.partition import dirichlet_partition, label_histogram
 from repro.data.pipeline import build_clients
 from repro.data.synthetic import MNIST_LIKE, make_image_dataset
 from repro.fl import client as client_mod
 from repro.fl import server as server_mod
 from repro.fl.paramspace import ParamSpace
-from repro.fl.simulation import FLConfig, Simulation
 from repro.launch import cohort as cohort_mod
 from repro.models.resnet import ResNetConfig, init_resnet, resnet_loss
 from repro.optim import optimizers as opt_mod
@@ -136,35 +136,38 @@ def test_adaptive_mu():
     assert mus[0] > mus[1] > mus[2] > 0  # weak devices pull harder
 
 
+def _run(selection="random", privacy=None, **training):
+    data, clients, params, loss_fn, eval_fn = _setup()
+    cfg = api.ExperimentConfig(
+        training=api.TrainingConfig(**training),
+        privacy=privacy or api.PrivacyConfig(),
+        orchestrator=api.OrchestratorConfig(selection=selection),
+    )
+    task = api.FederatedTask(loss_fn, eval_fn, params, clients, data["test"])
+    return api.Federation(cfg, task).run()
+
+
 @pytest.mark.parametrize("alg", ["fedavg", "fedprox", "fedadam", "fedyogi", "fednova", "scaffold"])
 def test_all_algorithms_run_one_round(alg):
-    data, clients, params, loss_fn, eval_fn = _setup()
-    cfg = FLConfig(algorithm=alg, selection="random", n_clients=6, clients_per_round=2,
-                   rounds=1, local_steps=2, batch_size=16, eval_every=1,
-                   server_lr=0.02 if alg in ("fedadam", "fedyogi") else 1.0)
-    sim = Simulation(cfg, loss_fn, eval_fn, params, clients, data["test"])
-    h = sim.run()
+    h = _run(algorithm=alg, n_clients=6, clients_per_round=2, rounds=1, local_steps=2,
+             batch_size=16, eval_every=1,
+             server_lr=0.02 if alg in ("fedadam", "fedyogi") else 1.0)
     assert len(h["acc"]) == 1 and np.isfinite(h["acc"][0])
     assert h["co2_g"][0] > 0 and h["duration_s"][0] > 0
 
 
 def test_secure_agg_matches_plain_aggregation():
     """The masked-ring path must reproduce plain FedAvg to quantizer precision."""
-    data, clients, params, loss_fn, eval_fn = _setup()
-    base = dict(algorithm="fedavg", selection="random", n_clients=6, clients_per_round=3,
-                rounds=2, local_steps=2, batch_size=16, eval_every=1, seed=7)
-    h_plain = Simulation(FLConfig(**base), loss_fn, eval_fn, params, clients, data["test"]).run()
-    h_sa = Simulation(FLConfig(secure_agg=True, sa_bits=24, **base), loss_fn, eval_fn,
-                      params, clients, data["test"]).run()
+    base = dict(algorithm="fedavg", n_clients=6, clients_per_round=3, rounds=2,
+                local_steps=2, batch_size=16, eval_every=1, seed=7)
+    h_plain = _run(**base)
+    h_sa = _run(privacy=api.PrivacyConfig(secure_agg=True, sa_bits=24), **base)
     assert abs(h_plain["final_acc"] - h_sa["final_acc"]) < 0.02
 
 
 def test_rl_green_smoke_with_emissions_accounting():
-    data, clients, params, loss_fn, eval_fn = _setup()
-    cfg = FLConfig(algorithm="fedavg", selection="rl_green", n_clients=6, clients_per_round=2,
-                   rounds=3, local_steps=2, batch_size=16, eval_every=1)
-    sim = Simulation(cfg, loss_fn, eval_fn, params, clients, data["test"])
-    h = sim.run()
+    h = _run(selection="rl_green", algorithm="fedavg", n_clients=6, clients_per_round=2,
+             rounds=3, local_steps=2, batch_size=16, eval_every=1)
     assert len(h["co2_g"]) == 3
     assert h["cum_co2_g"][-1] == pytest.approx(sum(h["co2_g"]))
     assert all(len(s) == 2 for s in h["selected"])

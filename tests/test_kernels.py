@@ -40,7 +40,7 @@ def test_flash_attention_fp32(case):
     B, T, S, H, K, hd, causal, window, cap = case
     q, k, v = _qkv(jax.random.PRNGKey(0), B, T, S, H, K, hd, jnp.float32)
     out = ops.flash_attention(q, k, v, causal=causal, window=window, logit_cap=cap,
-                              block_q=64, block_k=64)
+                              block_q=64, block_k=64, interpret=True)
     expect = ref.flash_attention_ref(q, k, v, causal=causal, window=window, logit_cap=cap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=3e-5, rtol=3e-5)
 
@@ -48,7 +48,7 @@ def test_flash_attention_fp32(case):
 @pytest.mark.parametrize("dtype,tol", [(jnp.bfloat16, 3e-2), (jnp.float32, 3e-5)])
 def test_flash_attention_dtypes(dtype, tol):
     q, k, v = _qkv(jax.random.PRNGKey(1), 2, 128, 128, 4, 2, 64, dtype)
-    out = ops.flash_attention(q, k, v, causal=True)
+    out = ops.flash_attention(q, k, v, causal=True, interpret=True)
     expect = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(expect, np.float32), atol=tol, rtol=tol
@@ -59,7 +59,7 @@ def test_flash_attention_dtypes(dtype, tol):
 def test_flash_attention_block_shapes(blocks):
     bq, bk = blocks
     q, k, v = _qkv(jax.random.PRNGKey(2), 1, 160, 160, 4, 4, 64, jnp.float32)
-    out = ops.flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+    out = ops.flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk, interpret=True)
     expect = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=3e-5, rtol=3e-5)
 
@@ -72,7 +72,7 @@ def test_masked_agg_kernel(n, P, bits):
     keys = list(jax.random.split(jax.random.PRNGKey(7), n))
     masked = jnp.stack([secure_agg.mask_update(q, k) for q, k in zip(qs, keys)])
     masks = jnp.stack([secure_agg.mask_stream(k, P) for k in keys])
-    out = ops.masked_aggregate(masked, masks, 1.0, bits)
+    out = ops.masked_aggregate(masked, masks, 1.0, bits, interpret=True)
     expect = ref.masked_aggregate_ref(masked, masks, 1.0, bits)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=1e-6)
     # and the decoded result matches the true float sum within quant error
@@ -138,7 +138,7 @@ def test_clip_quant_mask_roundtrips_through_masked_agg():
     rows = jnp.asarray(rows)
     masks = secure_agg.mask_rows(jax.random.PRNGKey(5), k, P)
     cipher = ops.clip_quant_mask(rows, masks, clip, bits, dim=dim)
-    dec = np.asarray(ops.masked_aggregate(cipher, masks, clip, bits))
+    dec = np.asarray(ops.masked_aggregate(cipher, masks, clip, bits, interpret=True))
     clipped, _ = dp_mod.clip_rows(rows[:, :dim], clip)
     bound = quantize.quant_error_bound(clip, bits) * k + 1e-6
     np.testing.assert_allclose(dec[:dim], np.asarray(clipped).sum(0), atol=bound)
@@ -173,3 +173,41 @@ def test_compress_traffic_roofline_model():
         compress_traffic(4, 1024, density=0.0)
     with pytest.raises(ValueError, match="k, P"):
         compress_traffic(0, 1024)
+
+
+def _dispatch_cases():
+    rng = np.random.default_rng(11)
+    k, dim, P = 4, 1000, 1024
+    rows = jnp.asarray(rng.normal(0, 0.5, (k, P)).astype(np.float32))
+    masks = secure_agg.mask_rows(jax.random.PRNGKey(9), k, P)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, k).astype(np.float32))
+    W = jnp.asarray(rng.uniform(0.0, 1.0, (k, k)).astype(np.float32))
+    q, kk, v = _qkv(jax.random.PRNGKey(4), 1, 72, 72, 4, 2, 48, jnp.float32)
+    return {
+        "flash_attention": (
+            lambda: ops.flash_attention(q, kk, v, causal=True, window=17),
+            lambda: ref.flash_attention_ref(q, kk, v, causal=True, window=17)),
+        "masked_aggregate": (
+            lambda: ops.masked_aggregate(rows.astype(jnp.uint32) + masks, masks, 1.0, 20),
+            lambda: ref.masked_aggregate_ref(rows.astype(jnp.uint32) + masks, masks, 1.0, 20)),
+        "clip_quant_mask": (
+            lambda: ops.clip_quant_mask(rows, masks, 1.0, 20, dim=dim),
+            lambda: ref.clip_quant_mask_ref(rows, masks, 1.0, 20, dim=dim)),
+        "staleness_aggregate": (
+            lambda: ops.staleness_aggregate(rows, w),
+            lambda: ref.staleness_aggregate_ref(rows, w)),
+        "gossip_mix": (
+            lambda: ops.gossip_mix(rows, W),
+            lambda: ref.gossip_mix_ref(rows, W)),
+    }
+
+
+@pytest.mark.parametrize("wrapper", ["flash_attention", "masked_aggregate", "clip_quant_mask",
+                                     "staleness_aggregate", "gossip_mix"])
+def test_default_dispatch_off_tpu_is_the_reference(wrapper):
+    """``interpret=None`` off the TPU runs the XLA reference of kernels/ref.py."""
+    assert jax.default_backend() != "tpu"
+    public, reference = _dispatch_cases()[wrapper]
+    out, expect = public(), reference()
+    assert out.shape == expect.shape and out.dtype == expect.dtype
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=1e-6)

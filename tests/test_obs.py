@@ -437,14 +437,6 @@ def test_phase_spans_nest_once_per_round(observed_runs, mode, name, parent):
 
 
 @pytest.mark.parametrize("mode", ["sync", "async_hier", "gossip"])
-def test_train_span_records_clients_per_pass(observed_runs, mode):
-    """The cohort trainers run one client per pass of the model."""
-    rows = obs.read_spans(os.path.join(observed_runs[mode]["dir"], "trace.jsonl"))
-    trains = [r for r in rows if r["name"] == "train"]
-    assert trains and all(r["attrs"]["clients_per_pass"] == 1 for r in trains)
-
-
-@pytest.mark.parametrize("mode", ["sync", "async_hier", "gossip"])
 def test_tracing_leaves_history_bitwise_identical(observed_runs, mode):
     run = observed_runs[mode]
     assert run["hist"] == run["hist_plain"]

@@ -173,12 +173,12 @@ def test_cohort_mesh_fallback_and_padding_indices():
 
 
 def test_sharded_simulation_matches_flat_engine():
-    """FLConfig(sharded=True) runs the whole engine through the shard_map
-    cohort path and reproduces the flat engine's trajectory."""
+    """TrainingConfig(sharded=True) runs the whole engine through the
+    shard_map cohort path and reproduces the flat engine's trajectory."""
+    from repro import api
     from repro.data.partition import dirichlet_partition
     from repro.data.pipeline import build_clients
     from repro.data.synthetic import MNIST_LIKE, make_image_dataset
-    from repro.fl.simulation import FLConfig, Simulation
     from repro.models.resnet import ResNetConfig, init_resnet, resnet_loss
 
     data = make_image_dataset(MNIST_LIKE, seed=1, n_train=400, n_test=128)
@@ -188,12 +188,19 @@ def test_sharded_simulation_matches_flat_engine():
     params = init_resnet(jax.random.PRNGKey(0), rcfg)
     loss_fn = lambda p, b: resnet_loss(p, rcfg, b)
     eval_fn = lambda p, b: resnet_loss(p, rcfg, b)[1]
-    base = dict(algorithm="fedavg", selection="random", n_clients=4, clients_per_round=2,
-                rounds=2, local_steps=2, batch_size=16, eval_every=1, seed=3)
-    h_flat = Simulation(FLConfig(**base), loss_fn, eval_fn, params, clients,
-                        data["test"]).run()
-    h_shard = Simulation(FLConfig(sharded=True, **base), loss_fn, eval_fn, params,
-                         clients, data["test"]).run()
+    task = api.FederatedTask(loss_fn, eval_fn, params, clients, data["test"])
+    base = dict(algorithm="fedavg", n_clients=4, clients_per_round=2, rounds=2,
+                local_steps=2, batch_size=16, eval_every=1, seed=3)
+
+    def run(**sharded):
+        cfg = api.ExperimentConfig(
+            training=api.TrainingConfig(**base, **sharded),
+            orchestrator=api.OrchestratorConfig(selection="random"),
+        )
+        return api.Federation(cfg, task).run()
+
+    h_flat = run()
+    h_shard = run(sharded=True)
     np.testing.assert_allclose(h_flat["acc"], h_shard["acc"], atol=1e-4)
     np.testing.assert_allclose(h_flat["loss"], h_shard["loss"], rtol=1e-5)
     assert h_flat["selected"] == h_shard["selected"]

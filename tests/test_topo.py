@@ -111,7 +111,7 @@ def test_gossip_mix_kernel_matches_ref_bitwise(k, P):
     rng = np.random.default_rng(k)
     rows = jnp.asarray(rng.normal(0, 0.5, (k, P)).astype(np.float32))
     W = jnp.asarray(graph_mod.plan("ring", k).mixing)
-    out = ops.gossip_mix(rows, W)  # interpret mode on CPU
+    out = ops.gossip_mix(rows, W, interpret=True)
     expect = ref.gossip_mix_ref(rows, W)
     assert out.shape == (k, P) and out.dtype == jnp.float32
     # the tiled kernel and the whole-row matmul are different programs that
@@ -137,13 +137,14 @@ def test_gossip_mix_preserves_average_and_contracts_disagreement():
 
 
 def test_mix_rows_pads_to_blocks_on_kernel_path():
-    """The TPU branch slices the padded output back to dim columns."""
+    """``mix_rows`` pads to whole blocks; the kernel's output, sliced back to
+    dim columns, is the unpadded reference's."""
     rng = np.random.default_rng(1)
     k, P = 4, 3000  # not a block multiple
     pspace = ParamSpace.build({"a": jnp.zeros((P,))})
     rows = jnp.asarray(rng.normal(0, 1, (k, P)).astype(np.float32))
     W = jnp.asarray(graph_mod.plan("full", k).mixing)
-    # force the explicit kernel path the TPU branch uses
+    # force the kernel, which the TPU runs by default
     out = ops.gossip_mix(pspace.pad_rows(rows), W, interpret=True)[:, : pspace.dim]
     np.testing.assert_array_equal(
         np.asarray(out), np.asarray(ref.gossip_mix_ref(rows, W))
